@@ -13,68 +13,12 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import algorithms, dedup, engine
 from repro.data.synth import barabasi_albert_condensed
-from repro.launch.mesh import largest_feasible_mesh
+from repro.distributed.sharding import edge_mesh, shard_graph_edges
 from repro.launch.orchestrator import Heartbeat, Supervisor
-
-
-def shard_graph(dev_graph, mesh):
-    """Place edge arrays of a DeviceCondensed across the mesh.
-
-    ``device_put`` needs divisible dims, so ragged edge lists are padded
-    with *inert* entries: padded in-edges point real node 0 at a fresh
-    dummy virtual node with no out-edges (and vice versa for out-edges),
-    so no complete path — hence zero propagated mass — is added.
-    """
-    n_dev = len(mesh.devices.flatten())
-    e_sh = NamedSharding(mesh, P(("data", "model")))
-    r = NamedSharding(mesh, P())
-
-    def place(x, sharding):
-        return jax.device_put(x, sharding)
-
-    def pad_edges(e, dummy_src, dummy_dst, n_src, n_dst):
-        pad = (-e.src.shape[0]) % n_dev
-        if pad == 0:
-            return engine.DeviceBipartite(
-                place(e.src, e_sh), place(e.dst, e_sh), n_src, n_dst
-            )
-        src = jnp.concatenate([e.src, jnp.full(pad, dummy_src, e.src.dtype)])
-        dst = jnp.concatenate([e.dst, jnp.full(pad, dummy_dst, e.dst.dtype)])
-        return engine.DeviceBipartite(place(src, e_sh), place(dst, e_sh),
-                                      n_src, n_dst)
-
-    chains = []
-    for chain in dev_graph.chains:
-        padded = []
-        for li, e in enumerate(chain):
-            # grow every virtual level by 2 dummies: dummy A has only
-            # in-edges, dummy B only out-edges -> no complete paths.
-            n_src = e.n_src + (2 if li > 0 else 0)
-            n_dst = e.n_dst + (2 if li < len(chain) - 1 else 0)
-            dummy_dst = e.n_dst if li < len(chain) - 1 else 0
-            dummy_src = e.n_src + 1 if li > 0 else 0
-            padded.append(pad_edges(e, dummy_src, dummy_dst, n_src, n_dst))
-        chains.append(tuple(padded))
-    corr = None
-    if dev_graph.correction is not None:
-        cs, cd, cm = dev_graph.correction
-        pad = (-cs.shape[0]) % n_dev
-        if pad:
-            cs = jnp.concatenate([cs, jnp.zeros(pad, cs.dtype)])
-            cd = jnp.concatenate([cd, jnp.zeros(pad, cd.dtype)])
-            cm = jnp.concatenate([cm, jnp.zeros(pad, cm.dtype)])  # count 0
-        corr = (place(cs, e_sh), place(cd, e_sh), place(cm, e_sh))
-    diag = place(dev_graph.diag_mult, r) if dev_graph.diag_mult is not None else None
-    return engine.DeviceCondensed(
-        chains=tuple(chains), direct=None, correction=corr, diag_mult=diag,
-        n_real=dev_graph.n_real, deduplicated=dev_graph.deduplicated,
-    )
 
 
 def main():
@@ -90,8 +34,8 @@ def main():
     # reference on one device
     pr_ref = np.asarray(algorithms.pagerank(dev, num_iters=20))
 
-    mesh = jax.make_mesh((n_dev // 2, 2), ("data", "model"))
-    sharded = shard_graph(dev, mesh)
+    mesh = edge_mesh((n_dev // 2, 2), ("data", "model"))
+    sharded = shard_graph_edges(dev, mesh)
     t0 = time.time()
     pr = np.asarray(algorithms.pagerank(sharded, num_iters=20))
     print(f"sharded PageRank on {n_dev} devices: {time.time()-t0:.2f}s; "
@@ -113,9 +57,9 @@ def main():
     print(f"supervisor: worker 3 declared dead; events={sup.events}")
     shape, axes = sup.remesh_plan(devices_per_worker=2)
     print(f"re-mesh plan on survivors: shape={shape} axes={axes}")
-    new_mesh = jax.make_mesh(shape, axes,
-                             devices=np.array(jax.devices()[: shape[0]*shape[1]]))
-    sharded2 = shard_graph(dev, new_mesh)
+    new_mesh = edge_mesh(shape, axes,
+                         devices=jax.devices()[: shape[0] * shape[1]])
+    sharded2 = shard_graph_edges(dev, new_mesh)
     pr2 = np.asarray(algorithms.pagerank(sharded2, num_iters=20))
     assert np.allclose(pr2, pr_ref, atol=1e-6)
     print("analysis resumed on the shrunken mesh; results identical")

@@ -145,8 +145,6 @@ def make_banded_pagerank(
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import pvary, shard_map
-
     vb = n_virt_banded // n_shards
     rb = n_real // n_shards
 
@@ -180,10 +178,10 @@ def make_banded_pagerank(
                 return (1.0 - damping) / n_real + damping * y_loc
 
             x0 = jnp.full((rb,), 1.0 / n_real, dtype=jnp.float32)
-            x0 = pvary(x0, axes)
+            x0 = jax.lax.pvary(x0, axes)
             return jax.lax.fori_loop(0, iters, body, x0)
 
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=tuple([P(axes)] * 8),

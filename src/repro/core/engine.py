@@ -192,7 +192,7 @@ class PackedOperands:
     slot_row: jnp.ndarray   # (n_slots,) int32
     row_start: jnp.ndarray  # (n_rt,) int32
     row_count: jnp.ndarray  # (n_rt,) int32
-    bitmaps: jnp.ndarray    # (n_slots, TILE, WORDS) uint32
+    bitmaps: jnp.ndarray    # (n_slots, WORDS, TILE) uint32
     crossover: Optional["CrossoverTable"] = None
 
 
@@ -222,8 +222,8 @@ class FusedOperands:
     slot_row: jnp.ndarray   # (n_slots,) int32
     row_start: jnp.ndarray  # (n_rt,) int32
     row_count: jnp.ndarray  # (n_rt,) int32
-    bitmaps: jnp.ndarray    # (n_main, TILE, WORDS) uint32
-    planes: jnp.ndarray     # (n_corr, P, TILE, WORDS) uint32
+    bitmaps: jnp.ndarray    # (n_main, WORDS, TILE) uint32
+    planes: jnp.ndarray     # (n_corr, P, WORDS, TILE) uint32
     plane_weights: Tuple[float, ...]
     n_h_pad: int
     n_x_pad: int

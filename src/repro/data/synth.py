@@ -18,6 +18,7 @@ Condensed-graph generators:
 """
 from __future__ import annotations
 
+import itertools
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -46,6 +47,67 @@ def zipf_sizes(n: int, mean: float, rng: np.random.Generator, a: float = 2.5) ->
 # Relational catalogs
 # ---------------------------------------------------------------------------
 
+def weighted_draws_without_replacement(
+    sizes: np.ndarray, p: np.ndarray, rng: np.random.Generator
+) -> np.ndarray:
+    """One weighted draw without replacement per group, all groups at once.
+
+    Group ``g`` receives ``sizes[g]`` distinct items of ``range(p.size)``
+    with the distribution of ``rng.choice(p.size, sizes[g], replace=False,
+    p=p)``: successive sampling, where each pick is drawn from ``p``
+    renormalized over the items not yet picked.  That equals the first
+    ``sizes[g]`` distinct values of an i.i.d. stream from ``p``, so every
+    round draws i.i.d. candidates for all groups still short, keeps each
+    group's earliest distinct values in stream order, and repeats for the
+    shortfall.  Returns the items grouped by ``g``, each group in pick
+    order (the layout of ``np.concatenate`` over per-group draws).
+    """
+    sizes = np.asarray(sizes, dtype=np.int64)
+    n = p.size
+    if np.any(sizes > np.count_nonzero(p > 0)):
+        raise ValueError("a group asks for more items than have weight")
+    cdf = np.cumsum(p, dtype=np.float64)
+    cdf /= cdf[-1]
+    done_keys, done_pos = [], []         # picks of groups already full
+    keys = np.empty(0, dtype=np.int64)   # group * n + item, short groups
+    pos = np.empty(0, dtype=np.int64)    # stream position of each pick
+    have = np.zeros(sizes.size, dtype=np.int64)
+    drawn = 0
+    for rounds in itertools.count():
+        need = sizes - have
+        short = np.flatnonzero(need > 0)
+        if short.size == 0:
+            break
+        # later rounds read further ahead in the stream: a group that must
+        # collect nearly every item would otherwise spend one round on
+        # each rare item it still lacks
+        group = np.repeat(short, need[short] << min(rounds, 10))
+        item = cdf.searchsorted(rng.random(group.size), side="right")
+        keys = np.concatenate([keys, group * n + item])
+        pos = np.concatenate([pos, drawn + np.arange(group.size)])
+        drawn += group.size
+        # first occurrence of each (group, item) in stream order; picks of
+        # earlier rounds sit earlier in the stream, so they stay
+        keys, first = np.unique(keys, return_index=True)
+        pos = pos[first]
+        # each group keeps its sizes[g] earliest picks
+        order = np.lexsort((pos, keys // n))
+        keys, pos = keys[order], pos[order]
+        g = keys // n
+        keep = np.arange(g.size) - np.searchsorted(g, g) < sizes[g]
+        keys, pos, g = keys[keep], pos[keep], g[keep]
+        # groups already full hold no keys here any more: keep their count
+        have = np.maximum(have, np.bincount(g, minlength=sizes.size))
+        full = have[g] == sizes[g]
+        done_keys.append(keys[full])
+        done_pos.append(pos[full])
+        keys, pos = keys[~full], pos[~full]
+    keys = np.concatenate(done_keys) if done_keys else keys
+    pos = np.concatenate(done_pos) if done_pos else pos
+    order = np.lexsort((pos, keys // n))
+    return keys[order] % n
+
+
 def dblp_catalog(
     n_authors: int = 2000,
     n_pubs: int = 3000,
@@ -58,9 +120,7 @@ def dblp_catalog(
     # Preferential-ish author assignment: zipf-weighted sampling.
     w = 1.0 / np.arange(1, n_authors + 1) ** 0.8
     w /= w.sum()
-    author_ids = np.concatenate(
-        [rng.choice(n_authors, size=s, replace=False, p=w) for s in sizes]
-    )
+    author_ids = weighted_draws_without_replacement(sizes, w, rng)
     years = rng.integers(1990, 2024, size=n_pubs)
     authors = Table(
         "Author",

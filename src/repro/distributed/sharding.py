@@ -31,6 +31,8 @@ __all__ = [
     "current_mesh",
     "GRAPH_RULES",
     "shard_frontier",
+    "edge_mesh",
+    "shard_graph_edges",
     "extraction_shard_range",
     "merge_schedule",
     "MultihostSpillExtraction",
@@ -158,6 +160,78 @@ def shard_frontier(x: jax.Array) -> jax.Array:
     if x.ndim == 2:
         return shard(x, "graph_nodes", "graph_batch")
     raise ValueError(f"frontier must be (n,) or (n, B); got rank {x.ndim}")
+
+
+def edge_mesh(
+    shape: Sequence[int],
+    axes: Sequence[str],
+    devices: Optional[Sequence] = None,
+) -> Mesh:
+    """A mesh for edge-sharded propagation, with automatic axes.
+
+    ``jax.make_mesh`` now returns explicit axes, under which the engine's
+    gather from an edge-sharded index array has no unambiguous output
+    sharding.  With automatic axes the compiler places that gather and
+    the segment reduction after it, adding the collectives they need.
+    """
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=auto, devices=devices
+    )
+
+
+def shard_graph_edges(graph, mesh: Mesh):
+    """A :class:`~repro.core.engine.DeviceCondensed` with its edge and
+    correction arrays split across every device of ``mesh``.
+
+    ``device_put`` needs divisible dims, so ragged edge lists are padded
+    with *inert* entries: padded in-edges point real node 0 at a fresh
+    dummy virtual node with no out-edges (and vice versa for out-edges),
+    so no complete path, hence no propagated mass, is added.  Padded
+    correction triples carry count 0.
+    """
+    import jax.numpy as jnp
+
+    from ..core.engine import DeviceBipartite, DeviceCondensed
+
+    n_dev = mesh.devices.size
+    spread = NamedSharding(mesh, PartitionSpec(tuple(mesh.axis_names)))
+
+    def padded(a, fill):
+        pad = (-a.shape[0]) % n_dev
+        if pad:
+            a = jnp.concatenate([a, jnp.full(pad, fill, a.dtype)])
+        return jax.device_put(a, spread)
+
+    chains = []
+    for chain in graph.chains:
+        layers = []
+        for li, e in enumerate(chain):
+            # grow every virtual level by 2 dummies: dummy A has only
+            # in-edges, dummy B only out-edges -> no complete paths.
+            first, last = li == 0, li == len(chain) - 1
+            n_src = e.n_src + (0 if first else 2)
+            n_dst = e.n_dst + (0 if last else 2)
+            layers.append(DeviceBipartite(
+                padded(e.src, 0 if first else e.n_src + 1),
+                padded(e.dst, 0 if last else e.n_dst),
+                n_src,
+                n_dst,
+            ))
+        chains.append(tuple(layers))
+    corr = None
+    if graph.correction is not None:
+        corr = tuple(padded(a, 0) for a in graph.correction)
+    replicated = NamedSharding(mesh, PartitionSpec())
+    diag = graph.diag_mult
+    return DeviceCondensed(
+        chains=tuple(chains),
+        direct=None,
+        correction=corr,
+        diag_mult=None if diag is None else jax.device_put(diag, replicated),
+        n_real=graph.n_real,
+        deduplicated=graph.deduplicated,
+    )
 
 
 def extraction_shard_range(

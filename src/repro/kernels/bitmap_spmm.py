@@ -19,8 +19,10 @@ TPU mapping (see DESIGN.md §6):
   index maps read them to route each slot's source tile and output tile —
   a data-dependent gather at tile granularity, which is the TPU-friendly
   kind.
-* bitmaps (128 x 4 uint32 = 2 KiB) are unpacked in-register into a dense
-  128x128 0/1 operand — 32x less HBM traffic than an f32 block.
+* bitmaps (4 x 128 uint32 = 2 KiB) are unpacked in-register into a dense
+  128x128 0/1 operand — 32x less HBM traffic than an f32 block.  Their
+  minor axis is the 128 columns, so the TPU keeps them in a compact
+  (4, 128) tiling that the kernel reads in place.
 * a (128, Fb) f32 VMEM scratch accumulates across a row tile's slots; the
   run table marks the first slot (init) and last slot (write-out), so
   each output tile is written exactly once.
@@ -33,7 +35,6 @@ TPU mapping (see DESIGN.md §6):
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -49,29 +50,32 @@ __all__ = [
     "default_interpret",
 ]
 
-# _CHUNK: column chunk width of the masked-select reduction (min/max
-# ops); lives in pack so the shared footprint formula sizes the
-# (TILE, _CHUNK, Fb) select intermediate (~512 KiB at Fb=128).
+# _CHUNK: source rows the masked select (min/max ops) reads from the
+# window per loop step, one aligned (8, Fb) sublane group; it lives in
+# pack, whose footprint formula still reserves a (TILE, _CHUNK, Fb)
+# intermediate for it.
+
+
+# The 0/1 mask times an f32 frontier must not round the frontier: at the
+# default precision the MXU takes f32 operands as one bf16 pass.
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def default_interpret() -> bool:
-    """Interpret mode policy: compiled on TPU, interpreted elsewhere.
-
-    Override with ``REPRO_PALLAS_INTERPRET=0|1`` (forcing compiled mode on
-    a non-TPU backend will fail inside Mosaic — it exists for TPU hosts
-    whose default backend is not the TPU plugin).
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.lower() not in ("0", "false", "no")
+    """Interpret mode policy: compiled on TPU, interpreted elsewhere."""
     return jax.default_backend() != "tpu"
 
 
 def _unpack_bits(words: jnp.ndarray) -> jnp.ndarray:
-    """(TILE, WORDS) uint32 -> (TILE, TILE) 0/1 uint32, in-register."""
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (TILE, WORDS, 32), 2)
-    bits = (words[:, :, None] >> shifts) & jnp.uint32(1)
-    return bits.reshape(TILE, TILE)
+    """(WORDS, TILE) uint32 -> (TILE, TILE) 0/1 int32, in-register: bit
+    ``b`` of word ``[w, c]`` is row ``32 w + b``, column ``c``
+    (:mod:`repro.kernels.pack`).
+
+    Mosaic casts int32, not uint32, to float, so the mask leaves as int32.
+    """
+    shifts = jax.lax.broadcasted_iota(jnp.uint32, (WORDS, 32, TILE), 1)
+    bits = (words[:, None, :] >> shifts) & jnp.uint32(1)
+    return bits.reshape(TILE, TILE).astype(jnp.int32)
 
 
 def _kernel(
@@ -79,7 +83,7 @@ def _kernel(
     slot_row_ref,   # scalar prefetch: (n_slots,) dst row tile per slot
     row_start_ref,  # scalar prefetch: (n_rt,) run table starts
     row_count_ref,  # scalar prefetch: (n_rt,) run table counts
-    bitmaps_ref,    # (1, TILE, WORDS) current slot's bitmap
+    bitmaps_ref,    # (1, WORDS, TILE) current slot's bitmap
     x_ref,          # (row_window, Fb) current source window (streamed)
     y_ref,          # (TILE, Fb) output tile of the slot's row
     acc_ref,        # VMEM scratch: (TILE, Fb) f32 accumulator
@@ -99,31 +103,38 @@ def _kernel(
     def _():
         acc_ref[...] = jnp.full(acc_ref.shape, init, acc_ref.dtype)
 
-    if window_tiles == 1:
-        x_tile = x_ref[...]
-    else:
-        # the fetched window spans window_tiles source tiles; this slot's
-        # bitmap addresses one of them (slot_src modulo the window)
-        off = (slot_src_ref[s] % window_tiles) * TILE
-        x_tile = jax.lax.dynamic_slice_in_dim(x_ref[...], off, TILE, axis=0)
-    bits = _unpack_bits(bitmaps_ref[0])
+    # the fetched window spans window_tiles source tiles; this slot's
+    # bitmap addresses one of them (slot_src modulo the window)
+    base = 0
+    if window_tiles > 1:
+        base = pl.multiple_of((slot_src_ref[s] % window_tiles) * TILE, TILE)
     if op == "sum":
-        mask = bits.astype(x_tile.dtype)
+        mask = _unpack_bits(bitmaps_ref[0]).astype(x_ref.dtype)
         acc_ref[...] += jnp.dot(
-            mask, x_tile, preferred_element_type=jnp.float32
+            mask,
+            x_ref[pl.ds(base, TILE), :],
+            preferred_element_type=jnp.float32,
+            precision=_EXACT,
         )
     else:
-        m = bits != 0
-        xf = x_tile.astype(jnp.float32)
+        # masked select, one source row at a time: column r of the mask,
+        # taken out by a lane reduction, gates row r of the window
+        mask = _unpack_bits(bitmaps_ref[0])
+        lane = jax.lax.broadcasted_iota(jnp.int32, (TILE, TILE), 1)
         fill = jnp.inf if op == "min" else -jnp.inf
         combine = jnp.minimum if op == "min" else jnp.maximum
-        reduce_ = jnp.min if op == "min" else jnp.max
 
         def body(c, acc):
-            mc = jax.lax.dynamic_slice_in_dim(m, c * _CHUNK, _CHUNK, axis=1)
-            xc = jax.lax.dynamic_slice_in_dim(xf, c * _CHUNK, _CHUNK, axis=0)
-            vals = jnp.where(mc[:, :, None], xc[None, :, :], fill)
-            return combine(acc, reduce_(vals, axis=1))
+            start = pl.multiple_of(base + c * _CHUNK, _CHUNK)
+            xc = x_ref[pl.ds(start, _CHUNK), :].astype(jnp.float32)
+            for k in range(_CHUNK):
+                hit = jnp.max(
+                    jnp.where(lane == c * _CHUNK + k, mask, 0),
+                    axis=1,
+                    keepdims=True,
+                )
+                acc = combine(acc, jnp.where(hit != 0, xc[k : k + 1, :], fill))
+            return acc
 
         acc_ref[...] = jax.lax.fori_loop(0, TILE // _CHUNK, body, acc_ref[...])
 
@@ -167,7 +178,7 @@ def _bitmap_spmm_pallas(
         grid=(f // feature_block, n_slots),
         in_specs=[
             pl.BlockSpec(
-                (1, TILE, WORDS), lambda j, s, ss, sr, rs, rc: (s, 0, 0)
+                (1, WORDS, TILE), lambda j, s, ss, sr, rs, rc: (s, 0, 0)
             ),
             pl.BlockSpec(
                 (row_window, feature_block),
@@ -196,8 +207,8 @@ def _fused_kernel(
     slot_row_ref,   # scalar prefetch: (n_slots,) dst row tile per slot
     row_start_ref,  # scalar prefetch: (n_rt,) run table starts
     row_count_ref,  # scalar prefetch: (n_rt,) run table counts
-    bitmaps_ref,    # (1, TILE, WORDS) current main slot's bitmap
-    planes_ref,     # (1, P, TILE, WORDS) current correction slot's planes
+    bitmaps_ref,    # (1, WORDS, TILE) current main slot's bitmap
+    planes_ref,     # (1, P, WORDS, TILE) current correction slot's planes
     h_ref,          # (TILE, Fb) last-hidden source tile (main slots)
     x_ref,          # (TILE, Fb) input-frontier source tile (corr slots)
     y_ref,          # (TILE, Fb) output tile of the slot's row
@@ -229,7 +240,8 @@ def _fused_kernel(
     def _():
         mask = _unpack_bits(bitmaps_ref[0]).astype(h_ref.dtype)
         acc_ref[...] += jnp.dot(
-            mask, h_ref[...], preferred_element_type=jnp.float32
+            mask, h_ref[...], preferred_element_type=jnp.float32,
+            precision=_EXACT,
         )
 
     @pl.when(is_corr)
@@ -238,7 +250,8 @@ def _fused_kernel(
         for k, w in enumerate(plane_weights):
             mask = _unpack_bits(planes_ref[0, k]).astype(x_ref.dtype)
             cacc = cacc + jnp.float32(w) * jnp.dot(
-                mask, x_ref[...], preferred_element_type=jnp.float32
+                mask, x_ref[...], preferred_element_type=jnp.float32,
+                precision=_EXACT,
             )
         cacc_ref[...] = cacc
 
@@ -279,11 +292,11 @@ def _bitmap_spmm_fused(
         grid=(f // feature_block, n_slots),
         in_specs=[
             pl.BlockSpec(
-                (1, TILE, WORDS),
+                (1, WORDS, TILE),
                 lambda j, s, kd, ms, cs, mi, ci, sr, rs, rc: (mi[s], 0, 0),
             ),
             pl.BlockSpec(
-                (1, n_planes, TILE, WORDS),
+                (1, n_planes, WORDS, TILE),
                 lambda j, s, kd, ms, cs, mi, ci, sr, rs, rc: (ci[s], 0, 0, 0),
             ),
             pl.BlockSpec(
@@ -325,8 +338,8 @@ def bitmap_spmm_fused_pallas(
     slot_row: jnp.ndarray,   # (n_slots,) int32
     row_start: jnp.ndarray,  # (n_rt,) int32
     row_count: jnp.ndarray,  # (n_rt,) int32
-    bitmaps: jnp.ndarray,    # (n_main, TILE, WORDS) uint32
-    planes: jnp.ndarray,     # (n_corr, P, TILE, WORDS) uint32
+    bitmaps: jnp.ndarray,    # (n_main, WORDS, TILE) uint32
+    planes: jnp.ndarray,     # (n_corr, P, WORDS, TILE) uint32
     h: jnp.ndarray,          # (n_h_pad, F) last-hidden frontier
     x: jnp.ndarray,          # (n_x_pad, F) original input frontier
     n_dst_pad: int,
@@ -376,7 +389,7 @@ def bitmap_spmm_pallas(
     slot_row: jnp.ndarray,   # (n_slots,) int32
     row_start: jnp.ndarray,  # (n_rt,) int32
     row_count: jnp.ndarray,  # (n_rt,) int32
-    bitmaps: jnp.ndarray,    # (n_slots, TILE, WORDS) uint32
+    bitmaps: jnp.ndarray,    # (n_slots, WORDS, TILE) uint32
     x: jnp.ndarray,          # (n_src_pad, F); row_window/fb multiples
     n_dst_pad: int,
     feature_block: int = 128,

@@ -32,7 +32,7 @@ from typing import Tuple
 
 import numpy as np
 
-from .pack import TILE, WORDS, BlockSparseBitmap
+from .pack import TILE, WORDS, BlockSparseBitmap, unpack_block
 
 __all__ = ["CorrectionPlanes", "FusedStream", "pack_correction", "build_fused_stream"]
 
@@ -49,7 +49,7 @@ class CorrectionPlanes:
     slot_row: np.ndarray       # (n_slots,) int32 — dst row tile per block
     row_start: np.ndarray      # (n_rt,) int32
     row_count: np.ndarray      # (n_rt,) int32 — may be zero
-    planes: np.ndarray         # (n_slots, n_planes, TILE, WORDS) uint32
+    planes: np.ndarray         # (n_slots, n_planes, WORDS, TILE) uint32
     plane_weights: Tuple[float, ...]  # 2**k per plane
     n_dst: int
     n_src: int
@@ -75,13 +75,10 @@ class CorrectionPlanes:
         dense = np.zeros(
             (self.n_row_tiles * TILE, self.n_src_tiles * TILE), np.float64
         )
-        shifts = np.arange(32, dtype=np.uint32)
         for s in range(self.n_slots):
             i, b = int(self.slot_row[s]), int(self.slot_src[s])
             for k, w in enumerate(self.plane_weights):
-                bits = (
-                    (self.planes[s, k][:, :, None] >> shifts) & 1
-                ).reshape(TILE, TILE)
+                bits = unpack_block(self.planes[s, k])
                 dense[i * TILE : (i + 1) * TILE, b * TILE : (b + 1) * TILE] += (
                     w * bits
                 )
@@ -115,21 +112,21 @@ def pack_correction(
     row_start = np.concatenate([[0], np.cumsum(row_count[:-1])]).astype(np.int32)
     r = cd % TILE
     c = cs % TILE
-    word = c // 32
-    bit = (c % 32).astype(np.uint32)
-    flat = np.zeros(n_slots * n_planes * TILE * WORDS, dtype=np.uint32)
+    word = r // 32
+    bit = (r % 32).astype(np.uint32)
+    flat = np.zeros(n_slots * n_planes * WORDS * TILE, dtype=np.uint32)
     for k in range(n_planes):
         sel = ((cmi >> k) & 1).astype(bool)
         if not sel.any():
             continue
-        lin = ((inv[sel] * n_planes + k) * TILE + r[sel]) * WORDS + word[sel]
+        lin = ((inv[sel] * n_planes + k) * WORDS + word[sel]) * TILE + c[sel]
         np.bitwise_or.at(flat, lin, np.uint32(1) << bit[sel])
     return CorrectionPlanes(
         slot_src=slot_src,
         slot_row=slot_row,
         row_start=row_start,
         row_count=row_count,
-        planes=flat.reshape(n_slots, n_planes, TILE, WORDS),
+        planes=flat.reshape(n_slots, n_planes, WORDS, TILE),
         plane_weights=tuple(float(2**k) for k in range(n_planes)),
         n_dst=n_dst,
         n_src=n_src,

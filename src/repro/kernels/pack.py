@@ -3,14 +3,19 @@
 The paper's BITMAP idea (per-virtual-node bitmaps consulted during
 traversal) reborn TPU-native: the 0/1 incidence matrix of a condensed
 layer is tiled into 128x128 blocks; only nonzero blocks are stored, each
-as a 128x4 uint32 bitmap (2 KiB instead of 64 KiB f32).  The Pallas kernel
+as a 4x128 uint32 bitmap (2 KiB instead of 64 KiB f32).  Bit ``b`` of
+word ``[w, c]`` is the cell (row ``32 w + b``, column ``c``): a block's
+bits run down its rows, so its minor axis is the 128 columns.  On the TPU
+the array then keeps the compact (4, 128) tiling that the kernel reads in
+place; a minor axis of 4 words would be padded to 128 lanes, and copied
+so on every kernel call.  The Pallas kernel
 unpacks a block's bits in VMEM and feeds the MXU with a dense 128x128
 operand — bandwidth-compressed SpMM (see DESIGN.md §6).
 
 Layout (streamed slot list + run table):
     slot_src  : (n_slots,) int32  — source-tile index per nonzero block
     slot_row  : (n_slots,) int32  — dst row-tile index per nonzero block
-    bitmaps   : (n_slots, TILE, TILE//32) uint32
+    bitmaps   : (n_slots, TILE//32, TILE) uint32
     row_start : (n_row_tiles,) int32 — first slot of each row tile
     row_count : (n_row_tiles,) int32 — slots in each row tile
 
@@ -56,12 +61,13 @@ STREAM_CHUNK = 8
 
 # Bit-field widths of pack_bipartite's combined sort key; derived from
 # the tile constants so the layout can't silently drift from them.
-_R_BITS = TILE.bit_length() - 1          # row-in-tile
-_W_BITS = WORDS.bit_length() - 1         # word-in-row
+_C_BITS = TILE.bit_length() - 1          # column-in-tile
+_W_BITS = WORDS.bit_length() - 1         # word-in-column
 _B_BITS = 5                              # bit-in-word (uint32)
 
 __all__ = [
     "BlockSparseBitmap",
+    "unpack_block",
     "pack_bipartite",
     "merge_block_sparse",
     "streamed_footprint_bytes",
@@ -71,6 +77,12 @@ __all__ = [
     "TILE",
     "WORDS",
 ]
+
+
+def unpack_block(words: np.ndarray) -> np.ndarray:
+    """One (WORDS, TILE) bitmap -> its dense (TILE, TILE) 0/1 block."""
+    shifts = np.arange(32, dtype=np.uint32)[None, :, None]
+    return ((words[:, None, :] >> shifts) & 1).reshape(TILE, TILE)
 
 
 def streamed_footprint_bytes(
@@ -162,7 +174,7 @@ class BlockSparseBitmap:
 
     slot_src: np.ndarray   # (n_slots,) int32
     slot_row: np.ndarray   # (n_slots,) int32
-    bitmaps: np.ndarray    # (n_slots, TILE, WORDS) uint32
+    bitmaps: np.ndarray    # (n_slots, WORDS, TILE) uint32
     row_start: np.ndarray  # (n_row_tiles,) int32
     row_count: np.ndarray  # (n_row_tiles,) int32
     n_dst: int             # logical rows
@@ -205,12 +217,11 @@ class BlockSparseBitmap:
         dense = np.zeros(
             (self.n_row_tiles * TILE, self.n_src_tiles * TILE), dtype=np.float32
         )
-        shifts = np.arange(32, dtype=np.uint32)
         for s in range(self.n_slots):
             w = self.bitmaps[s]
             if not w.any():
                 continue
-            bits = ((w[:, :, None] >> shifts) & 1).reshape(TILE, TILE)
+            bits = unpack_block(w)
             i = int(self.slot_row[s])
             b = int(self.slot_src[s])
             dense[i * TILE : (i + 1) * TILE, b * TILE : (b + 1) * TILE] += bits
@@ -283,7 +294,7 @@ def merge_block_sparse(parts: "list[BlockSparseBitmap]") -> BlockSparseBitmap:
     maps_c = (
         np.concatenate(maps)
         if maps and sum(m.shape[0] for m in maps)
-        else np.zeros((0, TILE, WORDS), dtype=np.uint32)
+        else np.zeros((0, WORDS, TILE), dtype=np.uint32)
     )
     key = rows_c * n_st + cols_c
     order = np.argsort(key, kind="stable")
@@ -318,7 +329,7 @@ def merge_block_sparse(parts: "list[BlockSparseBitmap]") -> BlockSparseBitmap:
     return BlockSparseBitmap(
         slot_src=slot_src,
         slot_row=slot_row,
-        bitmaps=bitmaps.reshape(n_slots, TILE, WORDS),
+        bitmaps=bitmaps.reshape(n_slots, WORDS, TILE),
         row_start=row_start,
         row_count=row_count,
         n_dst=n_dst,
@@ -379,8 +390,8 @@ def pack_bipartite(
     bs = src // TILE
     r = (dst % TILE).astype(np.int64)
     c = (src % TILE).astype(np.int64)
-    word = c // 32
-    bit = (c % 32).astype(np.uint32)
+    word = r // 32
+    bit = (r % 32).astype(np.uint32)
     bkey = bd.astype(np.int64) * n_st + bs
 
     if method == "scatter":
@@ -392,14 +403,14 @@ def pack_bipartite(
         # one sort does everything: the full key is unique per (src, dst)
         # cell (duplicate check), its high bits group blocks row-major
         # with source tiles ascending (the kernel's streaming order), and
-        # its (row, word) middle bits delimit the bitmap-word runs.  All
-        # field widths are powers of two, so packing/unpacking is pure
-        # shift/mask — the residual cost after the scatter is gone.
-        low = _R_BITS + _W_BITS + _B_BITS
+        # its (word, column) middle bits delimit the bitmap-word runs.
+        # All field widths are powers of two, so packing/unpacking is
+        # pure shift/mask — the residual cost after the scatter is gone.
+        low = _C_BITS + _W_BITS + _B_BITS
         full = (
             (bkey << low)
-            | (r << (_W_BITS + _B_BITS))
-            | (word << _B_BITS)
+            | (word << (_C_BITS + _B_BITS))
+            | (c << _B_BITS)
             | bit
         )
         order_e = np.argsort(full, kind="stable")
@@ -421,22 +432,22 @@ def pack_bipartite(
     flat = np.zeros(n_slots * TILE * WORDS, dtype=np.uint32)
     if src.size:
         if method == "scatter":
-            lin = (slot_of[inv] * TILE + r) * WORDS + word
+            lin = (slot_of[inv] * WORDS + word) * TILE + c
             np.bitwise_or.at(flat, lin, np.uint32(1) << bit)
         else:
             # slot_of is monotone over sorted blocks (pads append after
             # each row's real slots), so the sorted edge order is also
-            # sorted by (slot, row, word): reduceat folds each word run
+            # sorted by (slot, word, column): reduceat folds each word run
             block_of_edge = np.repeat(
                 slot_of[: uniq.size],
                 np.diff(np.r_[block_bounds, full_s.size]),
             )
             rw_s = (full_s >> _B_BITS) & (TILE * WORDS - 1)
-            lin_s = (block_of_edge << (_R_BITS + _W_BITS)) | rw_s
+            lin_s = (block_of_edge << (_C_BITS + _W_BITS)) | rw_s
             starts = np.flatnonzero(np.r_[True, lin_s[1:] != lin_s[:-1]])
             vals_s = np.uint32(1) << bit[order_e]
             flat[lin_s[starts]] = np.bitwise_or.reduceat(vals_s, starts)
-    bitmaps = flat.reshape(n_slots, TILE, WORDS)
+    bitmaps = flat.reshape(n_slots, WORDS, TILE)
     return BlockSparseBitmap(
         slot_src=slot_src,
         slot_row=slot_row,

@@ -20,6 +20,7 @@ import numpy as np
 from ..configs import registry
 from ..models import transformer
 from ..serve.server import BatchedServer, Request
+from .compile_cache import enable_compile_cache
 
 
 def _serve_lm(args) -> int:
@@ -111,6 +112,7 @@ def main() -> int:
     ap.add_argument("--replicas", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.graphs > 0:
         return _serve_graphs(args)
     return _serve_lm(args)

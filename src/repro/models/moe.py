@@ -21,7 +21,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 from ..configs.base import MoEConfig
 from ..distributed.sharding import shard
 from .layers import dense_init
@@ -218,7 +217,7 @@ def _moe_a2a(params, x, cfg: MoEConfig, mesh, ep_axis: str, token_axes):
 
     tok_spec = P(token_axes, None)
     w_spec3 = P(ep_axis, None, None)
-    y, aux, zl, drop = shard_map(
+    y, aux, zl, drop = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(tok_spec, P(None, None), w_spec3, w_spec3, w_spec3),

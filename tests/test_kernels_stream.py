@@ -295,14 +295,3 @@ def test_resolve_backend_policy():
     assert fits_vmem(128, 128, 4, n_slots=10_000)
     assert not fits_vmem(128, 128, 4, n_slots=1_000_000)
 
-
-def test_default_interpret_env_override(monkeypatch):
-    from repro.kernels.bitmap_spmm import default_interpret
-
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    import jax
-    assert default_interpret() == (jax.default_backend() != "tpu")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert default_interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert default_interpret() is False
