@@ -1,9 +1,10 @@
 """JAX's persistent compilation cache, at one fixed place.
 
-The cache key includes the directory, so a directory that moves between
-runs never hits.  ``JAX_COMPILATION_CACHE_DIR``, where it is set, is left
-to JAX, which reads it itself; otherwise the cache lives in ``.jax_cache``
-at the root of the checkout (listed in ``.gitignore``).
+A directory that is new on every run never finds what an earlier run
+compiled, so the directory is fixed.  ``JAX_COMPILATION_CACHE_DIR``,
+where it is set, is left to JAX, which reads it itself; otherwise the
+cache lives in ``.jax_cache`` at the root of the checkout (listed in
+``.gitignore``).
 """
 from __future__ import annotations
 
