@@ -355,11 +355,13 @@ def personalized_pagerank(
     x = seeds
 
     def body(_, x):
-        contrib = jnp.where(degb > 0, x / jnp.maximum(degb, 1.0), 0.0)
+        with jax.named_scope("ppr.update"):
+            contrib = jnp.where(degb > 0, x / jnp.maximum(degb, 1.0), 0.0)
         y = propagate(graph, contrib, PLUS_TIMES)
-        dangling = jnp.sum(jnp.where(degb > 0, 0.0, x), axis=0)
-        y = y + dangling * seeds
-        return (1.0 - damping) * seeds + damping * y
+        with jax.named_scope("ppr.update"):
+            dangling = jnp.sum(jnp.where(degb > 0, 0.0, x), axis=0)
+            y = y + dangling * seeds
+            return (1.0 - damping) * seeds + damping * y
 
     return jax.lax.fori_loop(0, num_iters, body, x)
 

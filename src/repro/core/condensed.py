@@ -25,6 +25,8 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
+
 __all__ = [
     "BipartiteEdges",
     "Chain",
@@ -290,7 +292,8 @@ class Chain:
             sub = BipartiteEdges(
                 src_sorted[a:b], dst_sorted[a:b], e0.n_src, e0.n_dst
             )
-            s, d, m = _compose_chain([sub] + list(self.edges[1:]))
+            with obs.span("condensed.expand"):
+                s, d, m = _compose_chain([sub] + list(self.edges[1:]))
             if accounting is not None:
                 accounting.end_chunk(int(m.sum()), s.size)
             yield s, d, m
@@ -418,12 +421,13 @@ def fold_path_pairs(
             and resident > budget_triples
             and len(runs_s) > 1
         ):
-            s, d, m = aggregate(
-                np.concatenate(runs_s),
-                np.concatenate(runs_d),
-                np.concatenate(runs_m),
-                n_dst,
-            )
+            with obs.span("condensed.fold"):
+                s, d, m = aggregate(
+                    np.concatenate(runs_s),
+                    np.concatenate(runs_d),
+                    np.concatenate(runs_m),
+                    n_dst,
+                )
             runs_s, runs_d, runs_m = [s], [d], [m]
             resident = s.size
             if accounting is not None:
@@ -431,12 +435,13 @@ def fold_path_pairs(
     if not runs_s:
         z = np.empty(0, dtype=np.int64)
         return z, z, z
-    out = aggregate(
-        np.concatenate(runs_s),
-        np.concatenate(runs_d),
-        np.concatenate(runs_m),
-        n_dst,
-    )
+    with obs.span("condensed.fold"):
+        out = aggregate(
+            np.concatenate(runs_s),
+            np.concatenate(runs_d),
+            np.concatenate(runs_m),
+            n_dst,
+        )
     if accounting is not None:
         accounting.runs_changed(out[0].size, merged=len(runs_s) > 1)
     return out
@@ -738,12 +743,13 @@ class CondensedGraph:
                     continue
                 if accounting is not None:
                     accounting.begin_chunk(b - a, budget=budget_triples)
-                s, d, m = _aggregate_pairs(
-                    src_sorted[a:b],
-                    dst_sorted[a:b],
-                    np.ones(b - a, dtype=np.int64),
-                    e.n_dst,
-                )
+                with obs.span("condensed.expand"):
+                    s, d, m = _aggregate_pairs(
+                        src_sorted[a:b],
+                        dst_sorted[a:b],
+                        np.ones(b - a, dtype=np.int64),
+                        e.n_dst,
+                    )
                 if accounting is not None:
                     accounting.end_chunk(b - a, s.size)
                 yield s, d, m

@@ -28,6 +28,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from .. import obs
 from .condensed import (
     BipartiteEdges,
     Chain,
@@ -270,18 +271,20 @@ def build_correction_streaming(
         budget_triples = max(int(budget_bytes) // TRIPLE_BYTES, 1)
     accounting = ExpansionAccounting(budget_triples=budget_triples)
     half = split_expansion_budget(budget_triples)
-    s, d, m = fold_path_pairs(
-        graph.iter_path_pairs(
-            chunk_rows=chunk_rows,
+    with obs.span("dedup.correction"):
+        s, d, m = fold_path_pairs(
+            graph.iter_path_pairs(
+                chunk_rows=chunk_rows,
+                budget_triples=half,
+                accounting=accounting,
+            ),
+            graph.n_real,
             budget_triples=half,
             accounting=accounting,
-        ),
-        graph.n_real,
-        budget_triples=half,
-        accounting=accounting,
-        aggregate=_aggregate_pairs_device if device_fold else None,
-    )
-    cs, cd, cm = _correction_from_multiplicities(s, d, m, drop_self_loops)
+            aggregate=_aggregate_pairs_device if device_fold else None,
+        )
+        with obs.span("dedup.finish"):
+            cs, cd, cm = _correction_from_multiplicities(s, d, m, drop_self_loops)
     return StreamedCorrection(cs, cd, cm, accounting)
 
 

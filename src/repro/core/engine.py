@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..distributed.sharding import shard_frontier
 from .condensed import BipartiteEdges, CondensedGraph, ExpandedGraph
 from .semiring import PLUS_TIMES, Semiring, kernelizable, segment_reduce
@@ -486,30 +487,35 @@ def to_device(
     """
     if isinstance(graph, ExpandedGraph):
         g = graph.without_self_loops() if drop_self_loops else graph
-        return DeviceExpanded(
-            jnp.asarray(g.src, dtype=jnp.int32),
-            jnp.asarray(g.dst, dtype=jnp.int32),
-            jnp.minimum(jnp.asarray(g.multiplicity, dtype=jnp.float32), 1.0),
-            g.n,
-            graph_version=int(graph_version),
-        )
-    chains = tuple(tuple(_dev_edges(e) for e in c.edges) for c in graph.chains)
-    direct = _dev_edges(graph.direct) if graph.direct is not None else None
-    corr = None
-    triples = _correction_triples(correction)
-    if triples is not None:
-        cs, cd, cm = triples
-        corr = (
-            jnp.asarray(cs, dtype=jnp.int32),
-            jnp.asarray(cd, dtype=jnp.int32),
-            jnp.asarray(cm, dtype=jnp.float32),
-        )
+        with obs.span("engine.upload"):
+            return _settled(DeviceExpanded(
+                jnp.asarray(g.src, dtype=jnp.int32),
+                jnp.asarray(g.dst, dtype=jnp.int32),
+                jnp.minimum(jnp.asarray(g.multiplicity, dtype=jnp.float32), 1.0),
+                g.n,
+                graph_version=int(graph_version),
+            ))
+    with obs.span("engine.upload"):
+        chains = tuple(tuple(_dev_edges(e) for e in c.edges) for c in graph.chains)
+        direct = _dev_edges(graph.direct) if graph.direct is not None else None
+        corr = None
+        triples = _correction_triples(correction)
+        if triples is not None:
+            cs, cd, cm = triples
+            corr = (
+                jnp.asarray(cs, dtype=jnp.int32),
+                jnp.asarray(cd, dtype=jnp.int32),
+                jnp.asarray(cm, dtype=jnp.float32),
+            )
+        chains, direct, corr = _settled((chains, direct, corr))
     diag = None
     if drop_self_loops and corr is None:
         # Full self-path multiplicity: DEDUP-1's uniqueness invariant is
         # off-diagonal only — u reaches itself once per containing virtual
         # node, and all of those must be subtracted.
-        diag = jnp.asarray(self_path_counts(graph), dtype=jnp.float32)
+        counts = self_path_counts(graph)
+        with obs.span("engine.upload"):
+            diag = _settled(jnp.asarray(counts, dtype=jnp.float32))
     return DeviceCondensed(
         chains=chains,
         direct=direct,
@@ -521,15 +527,24 @@ def to_device(
     )
 
 
+def _settled(tree):
+    """``tree``, once its arrays are on the device if the recorder is on,
+    so that an ``engine.upload`` span times the transfer, not its enqueue."""
+    if obs.enabled():
+        jax.block_until_ready(tree)
+    return tree
+
+
 def _upload_operands(bsb, crossover=None) -> PackedOperands:
-    return PackedOperands(
-        slot_src=jnp.asarray(bsb.slot_src),
-        slot_row=jnp.asarray(bsb.slot_row),
-        row_start=jnp.asarray(bsb.row_start),
-        row_count=jnp.asarray(bsb.row_count),
-        bitmaps=jnp.asarray(bsb.bitmaps),
-        crossover=crossover,
-    )
+    with obs.span("engine.upload"):
+        return _settled(PackedOperands(
+            slot_src=jnp.asarray(bsb.slot_src),
+            slot_row=jnp.asarray(bsb.slot_row),
+            row_start=jnp.asarray(bsb.row_start),
+            row_count=jnp.asarray(bsb.row_count),
+            bitmaps=jnp.asarray(bsb.bitmaps),
+            crossover=crossover,
+        ))
 
 
 def _measure_direction(bsb, dev_src, dev_dst, n_src, n_dst, measure_kwargs):
@@ -578,10 +593,11 @@ def _pack_edges(
     n_src_pad = max(-(-e.n_src // TILE), 1) * TILE
     n_dst_pad = max(-(-e.n_dst // TILE), 1) * TILE
     try:
-        fwd_bsb = pack_bipartite(e, method=pack_method, shard_edges=shard_edges)
-        rev_bsb = pack_bipartite(
-            e.reversed(), method=pack_method, shard_edges=shard_edges
-        )
+        with obs.span("engine.pack"):
+            fwd_bsb = pack_bipartite(e, method=pack_method, shard_edges=shard_edges)
+            rev_bsb = pack_bipartite(
+                e.reversed(), method=pack_method, shard_edges=shard_edges
+            )
         fwd_table = rev_table = None
         if measure:
             kw = measure_kwargs or {}
@@ -612,23 +628,24 @@ def _pack_edges(
 def _upload_fused(stream, main_bsb, corr_planes) -> FusedOperands:
     from ..kernels.pack import TILE
 
-    return FusedOperands(
-        kind=jnp.asarray(stream.kind),
-        main_src=jnp.asarray(stream.main_src),
-        corr_src=jnp.asarray(stream.corr_src),
-        main_idx=jnp.asarray(stream.main_idx),
-        corr_idx=jnp.asarray(stream.corr_idx),
-        slot_row=jnp.asarray(stream.slot_row),
-        row_start=jnp.asarray(stream.row_start),
-        row_count=jnp.asarray(stream.row_count),
-        bitmaps=jnp.asarray(main_bsb.bitmaps),
-        planes=jnp.asarray(corr_planes.planes),
-        plane_weights=corr_planes.plane_weights,
-        n_h_pad=main_bsb.n_src_tiles * TILE,
-        n_x_pad=corr_planes.n_src_tiles * TILE,
-        n_out=main_bsb.n_dst,
-        n_out_pad=main_bsb.n_row_tiles * TILE,
-    )
+    with obs.span("engine.upload"):
+        return _settled(FusedOperands(
+            kind=jnp.asarray(stream.kind),
+            main_src=jnp.asarray(stream.main_src),
+            corr_src=jnp.asarray(stream.corr_src),
+            main_idx=jnp.asarray(stream.main_idx),
+            corr_idx=jnp.asarray(stream.corr_idx),
+            slot_row=jnp.asarray(stream.slot_row),
+            row_start=jnp.asarray(stream.row_start),
+            row_count=jnp.asarray(stream.row_count),
+            bitmaps=jnp.asarray(main_bsb.bitmaps),
+            planes=jnp.asarray(corr_planes.planes),
+            plane_weights=corr_planes.plane_weights,
+            n_h_pad=main_bsb.n_src_tiles * TILE,
+            n_x_pad=corr_planes.n_src_tiles * TILE,
+            n_out=main_bsb.n_dst,
+            n_out_pad=main_bsb.n_row_tiles * TILE,
+        ))
 
 
 def _build_fused(
@@ -666,15 +683,15 @@ def _build_fused(
     n = graph.n_real
     if last_fwd_bsb.n_dst != n or first_rev_bsb.n_dst != n:
         return None, None, "endpoint_mismatch"
-    corr_fwd = pack_correction(cs, cd, cm, n_src=n, n_dst=n)
-    corr_rev = pack_correction(cd, cs, cm, n_src=n, n_dst=n)
-    fused_fwd = _upload_fused(
-        build_fused_stream(last_fwd_bsb, corr_fwd), last_fwd_bsb, corr_fwd
-    )
-    fused_rev = _upload_fused(
-        build_fused_stream(first_rev_bsb, corr_rev), first_rev_bsb, corr_rev
-    )
-    return fused_fwd, fused_rev, ""
+    with obs.span("engine.pack"):
+        corr_fwd = pack_correction(cs, cd, cm, n_src=n, n_dst=n)
+        corr_rev = pack_correction(cd, cs, cm, n_src=n, n_dst=n)
+    fused = []
+    for bsb, planes in ((last_fwd_bsb, corr_fwd), (first_rev_bsb, corr_rev)):
+        with obs.span("engine.pack"):
+            stream = build_fused_stream(bsb, planes)
+        fused.append(_upload_fused(stream, bsb, planes))
+    return fused[0], fused[1], ""
 
 
 def to_device_packed(
@@ -1108,15 +1125,18 @@ def propagate(
         h = x
         fuse_here = fused is not None and ci == len(graph.chains) - 1
         for si, e in enumerate(seq[:-1] if fuse_here else seq):
-            h = _layer_propagate(graph, semiring, e, h, reverse)
-            if w_seq is not None and si < len(seq) - 1:
-                h = semiring.mul(h, _bcast(jnp.asarray(w_seq[si]), h))
+            with jax.named_scope("engine.layer"):
+                h = _layer_propagate(graph, semiring, e, h, reverse)
+                if w_seq is not None and si < len(seq) - 1:
+                    h = semiring.mul(h, _bcast(jnp.asarray(w_seq[si]), h))
         if fuse_here:
-            h = _fused_layer_spmm(fused, h, x, graph.feature_block)
+            with jax.named_scope("engine.fused"):
+                h = _fused_layer_spmm(fused, h, x, graph.feature_block)
         h = _apply_hop(semiring, h, hop_weight)
         y = h if y is None else semiring.add(y, h)
     if graph.direct is not None:
-        h = _layer_propagate(graph, semiring, graph.direct, x, reverse)
+        with jax.named_scope("engine.layer"):
+            h = _layer_propagate(graph, semiring, graph.direct, x, reverse)
         h = _apply_hop(semiring, h, hop_weight)
         y = h if y is None else semiring.add(y, h)
     if y is None:
@@ -1128,14 +1148,15 @@ def propagate(
         if graph.correction is not None and fused is not None:
             pass  # already subtracted inside the fused kernel epilogue
         elif graph.correction is not None:
-            cs, cd, cm = graph.correction
-            src, dst = (cd, cs) if reverse else (cs, cd)
-            corr = jax.ops.segment_sum(
-                _gather(x, src) * _bcast(cm, _gather(x, src)),
-                dst,
-                num_segments=graph.n_real,
-            )
-            y = y - _apply_hop(semiring, corr, hop_weight)
+            with jax.named_scope("engine.correction"):
+                cs, cd, cm = graph.correction
+                src, dst = (cd, cs) if reverse else (cs, cd)
+                corr = jax.ops.segment_sum(
+                    _gather(x, src) * _bcast(cm, _gather(x, src)),
+                    dst,
+                    num_segments=graph.n_real,
+                )
+                y = y - _apply_hop(semiring, corr, hop_weight)
         elif graph.diag_mult is not None:
             y = y - _apply_hop(
                 semiring, x * _bcast(graph.diag_mult, x), hop_weight

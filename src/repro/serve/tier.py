@@ -52,6 +52,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core import algorithms
 from ..core import dedup as _dedup
 from ..core import engine as _engine
@@ -167,10 +168,19 @@ class ResultCacheStats:
 class _Executable:
     """One compiled propagation entry: the jitted callable plus trace
     evidence (``traces[0]`` increments only when jax actually re-traces
-    the wrapper — the honest no-retrace signal tests pin)."""
+    the wrapper — the honest no-retrace signal tests pin).  Its first
+    trace also records the dispatch path it took: ``kernel_layers`` Pallas
+    layer steps in the traced program (a loop body counts once) and the
+    fused-epilogue ``standdowns`` by reason, which every call counts while
+    the recorder (:mod:`repro.obs`) is on.  A jitted helper that another
+    executable traced first (``out_degrees`` does not depend on the batch
+    width) is not traced again, so its decisions count under that
+    executable alone."""
 
     fn: object
     traces: List[int]
+    kernel_layers: int = 0
+    standdowns: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 class _Tenant:
@@ -278,6 +288,8 @@ class GraphServingTier:
             collections.OrderedDict()
         )
         self._pending_qids: set = set()
+        # qid -> host clock at admission, kept only while the recorder is on
+        self._admitted: Dict[int, float] = {}
         self.now = 0.0
         self._tick = 0
         # caches
@@ -420,29 +432,30 @@ class GraphServingTier:
         tenant.last_used = self._tick
         if tenant.device is not None:
             return
-        to_dev = _engine.to_device_packed if tenant.packed else _engine.to_device
-        exact = to_dev(
-            tenant.host,
-            correction=tenant.correction,
-            drop_self_loops=tenant.drop_self_loops,
-            graph_version=tenant.version,
-        )
-        counts = None
-        nbytes = device_graph_bytes(exact)
-        if tenant.with_counts:
-            counts = to_dev(
-                tenant.host, drop_self_loops=False,
+        with obs.span("tier.resident", tenant=tenant.name):
+            to_dev = _engine.to_device_packed if tenant.packed else _engine.to_device
+            exact = to_dev(
+                tenant.host,
+                correction=tenant.correction,
+                drop_self_loops=tenant.drop_self_loops,
                 graph_version=tenant.version,
             )
-            nbytes += device_graph_bytes(counts)
-        while not self.budget.would_fit(nbytes):
-            if not self._evict_lru(exclude=tenant.name):
-                break   # nothing left to evict: charge() raises below
-        self.budget.charge(nbytes, f"tenant {tenant.name!r}")
-        tenant.device = exact
-        tenant.counts_device = counts
-        tenant.resident_bytes = nbytes
-        tenant.n_uploads += 1
+            counts = None
+            nbytes = device_graph_bytes(exact)
+            if tenant.with_counts:
+                counts = to_dev(
+                    tenant.host, drop_self_loops=False,
+                    graph_version=tenant.version,
+                )
+                nbytes += device_graph_bytes(counts)
+            while not self.budget.would_fit(nbytes):
+                if not self._evict_lru(exclude=tenant.name):
+                    break   # nothing left to evict: charge() raises below
+            self.budget.charge(nbytes, f"tenant {tenant.name!r}")
+            tenant.device = exact
+            tenant.counts_device = counts
+            tenant.resident_bytes = nbytes
+            tenant.n_uploads += 1
 
     def _evict_device(self, tenant: _Tenant, invalidation: bool = False) -> None:
         if tenant.device is None:
@@ -505,19 +518,16 @@ class GraphServingTier:
     def _build_executable(self, kind: str) -> _Executable:
         import jax
 
-        traces = [0]
         if kind == "bfs":
             max_iters = self.bfs_max_iters
 
             def raw(graph, sources):
-                traces[0] += 1
                 return algorithms.bfs_multi(graph, sources, max_iters=max_iters)
 
         elif kind == "ppr":
             damping, iters = self.damping, self.ppr_iters
 
             def raw(graph, sources):
-                traces[0] += 1
                 seeds = algorithms.one_hot_frontier(
                     algorithms.n_nodes(graph), sources
                 )
@@ -528,13 +538,11 @@ class GraphServingTier:
         elif kind == "common_neighbors":
 
             def raw(graph, sources):
-                traces[0] += 1
                 return algorithms.common_neighbors_multi(graph, sources)
 
         elif kind == "shortest":
 
             def raw(graph, sources, layer_weights):
-                traces[0] += 1
                 return algorithms.shortest_paths_multi(
                     graph, sources, layer_weights=layer_weights
                 )
@@ -542,7 +550,6 @@ class GraphServingTier:
         elif kind == "widest":
 
             def raw(graph, sources, layer_capacities):
-                traces[0] += 1
                 return algorithms.widest_paths_multi(
                     graph, sources, layer_capacities=layer_capacities
                 )
@@ -551,7 +558,6 @@ class GraphServingTier:
             # host-driven: one pivot sweep answers the whole batch — each
             # column is the queried node's SCC membership indicator
             def raw(graph, sources):
-                traces[0] += 1
                 labels = algorithms.scc_labels(graph)
                 cols = labels[np.asarray(sources)]
                 return (labels[:, None] == cols[None, :]).astype(np.float32)
@@ -561,13 +567,31 @@ class GraphServingTier:
             # per-node triangle-count vector (the node is a handle, the
             # batch shares one blocked sweep)
             def raw(graph, sources):
-                traces[0] += 1
                 t = algorithms.triangle_counts(graph).astype(np.float32)
                 return np.tile(t[:, None], (1, int(np.asarray(sources).size)))
 
-        if kind in HOST_KINDS:
-            return _Executable(fn=raw, traces=traces)
-        return _Executable(fn=jax.jit(raw), traces=traces)
+        entry = _Executable(fn=None, traces=[0])
+
+        def serve(*args):
+            entry.traces[0] += 1
+            first = entry.traces[0] == 1
+            if first:
+                layers0 = _engine.KERNEL_DISPATCH_COUNT
+                standdowns0 = dict(_engine.KERNEL_STANDDOWN_COUNT)
+            out = raw(*args)
+            if first:
+                entry.kernel_layers = _engine.KERNEL_DISPATCH_COUNT - layers0
+                entry.standdowns = {
+                    r: n - standdowns0.get(r, 0)
+                    for r, n in _engine.KERNEL_STANDDOWN_COUNT.items()
+                    if n != standdowns0.get(r, 0)
+                }
+            return out
+
+        # the executable's name is the module name the device trace shows
+        serve.__name__ = serve.__qualname__ = f"serve_{kind}"
+        entry.fn = serve if kind in HOST_KINDS else jax.jit(serve)
+        return entry
 
     # -- admission ------------------------------------------------------------
 
@@ -618,6 +642,7 @@ class GraphServingTier:
             if hit is not None:
                 self.result_stats.hits += 1
                 self.stats.n_queries += 1
+                obs.sample("tier.queue_wait_s", 0.0)
                 return ServeResult(
                     qid=req.qid, tenant=req.tenant, kind=req.kind,
                     node=req.node, value=hit, graph_version=tenant.version,
@@ -628,6 +653,8 @@ class GraphServingTier:
         qkey = (req.tenant, req.kind)
         self._queues.setdefault(qkey, []).append(req)
         self._pending_qids.add(req.qid)
+        if obs.enabled():
+            self._admitted[req.qid] = time.perf_counter()
         return None
 
     @property
@@ -667,37 +694,54 @@ class GraphServingTier:
         group, rest = queue[: self.max_batch], queue[self.max_batch :]
         self._queues[key] = rest
         t = self.tenants[tname]
+        width = self._bucket_width(len(group))
+        with obs.span("tier.step", kind=kind, width=width, fill=len(group)):
+            return self._run_batch(t, kind, group, width)
+
+    def _run_batch(
+        self, t: _Tenant, kind: str, group: List[ServeRequest], width: int
+    ) -> List[ServeResult]:
         t0 = time.perf_counter()
+        if self._admitted:
+            for q in group:
+                admitted = self._admitted.pop(q.qid, None)
+                if admitted is not None:
+                    obs.sample("tier.queue_wait_s", t0 - admitted)
         self._ensure_resident(t)
         graph = t.graph_for(kind)
-        width = self._bucket_width(len(group))
-        nodes = [int(q.node) for q in group]
-        nodes += [nodes[0]] * (width - len(nodes))
-        entry = self._executable(
-            kind, width, graph_shape_signature(graph)
-        )
-        call = (with_graph_version(graph, 0), jnp.asarray(nodes, dtype=jnp.int32))
-        if kind in WEIGHTED_KINDS:
-            res = np.asarray(entry.fn(*call, t.weights_for(kind)))
-        else:
-            res = np.asarray(entry.fn(*call))
+        with obs.span("tier.dispatch"):
+            nodes = [int(q.node) for q in group]
+            nodes += [nodes[0]] * (width - len(nodes))
+            entry = self._executable(
+                kind, width, graph_shape_signature(graph)
+            )
+            call = (with_graph_version(graph, 0), jnp.asarray(nodes, dtype=jnp.int32))
+            if kind in WEIGHTED_KINDS:
+                call += (t.weights_for(kind),)
+            out = entry.fn(*call)
+        obs.count("tier.kernel_layer_calls", entry.kernel_layers)
+        for reason, n in entry.standdowns.items():
+            obs.count(f"tier.standdown.{reason}", n)
+        with obs.span("tier.fetch"):
+            res = np.asarray(out)
         dt = time.perf_counter() - t0
         self.now += dt
         self.stats.record_batch(len(group), width)
-        out: List[ServeResult] = []
-        for i, q in enumerate(group):
-            value = res[:, i]
-            ckey = (tname, kind, int(q.node), t.version)
-            if self.result_cache_enabled:
-                self._results[ckey] = value
-            self._pending_qids.discard(q.qid)
-            self.stats.n_queries += 1
-            out.append(ServeResult(
-                qid=q.qid, tenant=tname, kind=kind, node=q.node,
-                value=value, graph_version=t.version, cached=False,
-                arrival_time=q.arrival_time, done_time=self.now,
-                batch_width=width, batch_fill=len(group),
-            ))
+        with obs.span("tier.record"):
+            out = []
+            for i, q in enumerate(group):
+                value = res[:, i]
+                ckey = (t.name, kind, int(q.node), t.version)
+                if self.result_cache_enabled:
+                    self._results[ckey] = value
+                self._pending_qids.discard(q.qid)
+                self.stats.n_queries += 1
+                out.append(ServeResult(
+                    qid=q.qid, tenant=t.name, kind=kind, node=q.node,
+                    value=value, graph_version=t.version, cached=False,
+                    arrival_time=q.arrival_time, done_time=self.now,
+                    batch_width=width, batch_fill=len(group),
+                ))
         return out
 
     def take_handoff(self) -> List[ServeResult]:

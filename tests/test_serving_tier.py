@@ -28,6 +28,7 @@ from repro.core.dedup import graph_from_membership
 from repro.core.engine import ResidencyBudget, ResidencyError
 from repro.data.synth import dblp_catalog
 from repro.launch.cells import place_serving_replicas
+from repro.serve import tier as serve_tier
 from repro.serve import (
     GraphQuery,
     GraphQueryServer,
@@ -460,3 +461,99 @@ def test_tier_rejects_mismatched_weight_structure_at_admission():
     tier.add_tenant("ok", g, layer_weights=ok, layer_capacities=ok)
     res = tier.serve(_reqs("ok", "shortest", [0]))
     assert np.asarray(res[0]).shape == (16,)
+
+
+# ---------------------------------------------------------------------------
+# What the tier records while repro.obs is on
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def recording():
+    from repro import obs
+
+    obs.reset()
+    obs.enable()
+    yield obs
+    obs.disable()
+    obs.reset()
+
+
+@pytest.mark.parametrize("kind", sorted(serve_tier.KINDS))
+def test_executables_are_named_per_kind(kind):
+    tier = GraphServingTier()
+    assert tier._build_executable(kind).fn.__name__ == f"serve_{kind}"
+
+
+def test_executable_module_carries_the_kind_name():
+    tier = _two_tenant_tier()
+    tier.serve(_reqs("A", "ppr", range(3)))
+    (key, entry), = tier._executables.items()
+    graph = engine.with_graph_version(tier.tenants["A"].device, 0)
+    text = entry.fn.lower(graph, np.zeros(key[1], np.int32)).as_text()
+    assert "@jit_serve_ppr" in text
+
+
+def test_kernel_layer_calls_count_every_call(monkeypatch, recording):
+    """The dispatch counters of ``engine`` move only while a program is
+    traced; the tier's per-call counters move with every batch."""
+    import functools
+
+    monkeypatch.setattr(
+        engine, "to_device_packed",
+        functools.partial(engine.to_device_packed, backend="pallas"),
+    )
+    rng = np.random.default_rng(5)
+    tier = GraphServingTier(max_batch=4, result_cache=False)
+    tier.add_tenant("A", random_membership_graph(16, 6, 3, rng), packed=True,
+                    with_counts=False)
+    engine.reset_kernel_dispatch_count()
+    tier.serve(_reqs("A", "ppr", range(4)))
+    (entry,) = tier._executables.values()
+    traced = engine.KERNEL_DISPATCH_COUNT
+    assert entry.kernel_layers == traced > 0
+    for calls in (1, 2, 3):
+        if calls > 1:
+            tier.serve(_reqs("A", "ppr", range(4), qid0=10 * calls))
+        counts = recording.snapshot()["counts"]
+        assert counts["tier.kernel_layer_calls"] == calls * traced
+        for reason, n in entry.standdowns.items():
+            assert counts[f"tier.standdown.{reason}"] == calls * n
+    assert engine.KERNEL_DISPATCH_COUNT == traced   # trace time only
+    assert entry.traces[0] == 1
+
+
+def test_queue_wait_samples_match_requests_served(recording):
+    tier = _two_tenant_tier()
+    first = tier.serve(_reqs("A", "bfs", range(6)))
+    waits = recording.snapshot()["samples"]["tier.queue_wait_s"]
+    assert len(waits) == len(first) == 6
+    assert all(0.0 <= w < 60.0 for w in waits)
+    # answered from the result cache: no queue, a wait of 0
+    hits = [tier.submit(r) for r in _reqs("A", "bfs", range(2), qid0=20)]
+    assert all(h is not None and h.cached for h in hits)
+    waits = recording.snapshot()["samples"]["tier.queue_wait_s"]
+    assert len(waits) == 8 and waits[-2:] == [0.0, 0.0]
+    assert tier._admitted == {}
+
+
+def test_step_spans_and_batch_fill_agree_with_server_stats(recording):
+    tier = _two_tenant_tier(result_cache=False)
+    results = []
+    for kind, n in (("bfs", 6), ("ppr", 3), ("bfs", 8), ("common_neighbors", 5)):
+        for r in _reqs("A", kind, range(n), qid0=len(results) + 100):
+            tier.submit(r)
+        results += tier.drain()
+    spans = recording.snapshot()["spans"]
+    n_batches = tier.stats.n_batches
+    assert n_batches == 4
+    for name in ("tier.step", "tier.dispatch", "tier.fetch", "tier.record"):
+        assert spans[name]["count"] == n_batches, name
+    children = sum(spans[k]["seconds"] for k in ("tier.resident", "tier.dispatch",
+                                                 "tier.fetch", "tier.record"))
+    assert children <= spans["tier.step"]["seconds"]
+    # one result per real query, each carrying its batch's fill and width
+    batches = {(r.kind, r.batch_width, r.batch_fill) for r in results}
+    fill = sum(f for _, _, f in batches)
+    slots = sum(w for _, w, _ in batches)
+    assert fill == tier.stats.queries_batched == len(results)
+    assert fill / slots == pytest.approx(tier.stats.occupancy)
