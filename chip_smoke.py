@@ -366,7 +366,14 @@ def four_chip_phase(b: Build, devices) -> dict:
     mesh = edge_mesh((2, 2), ("data", "model"), devices=devices[:4])
     sharded = shard_graph_edges(dev, mesh)
     spans = set()
-    for leaf in jax.tree_util.tree_leaves(sharded):
+    rows = [sharded.correction.fwd]
+    if sharded.correction.rev is not None:
+        rows.append(sharded.correction.rev)
+    for r in rows:
+        assert r.node_row.sharding.is_fully_replicated, "a node map is split"
+    for leaf in jax.tree_util.tree_leaves(
+        (sharded.chains, [(r.idx, r.weight) for r in rows])
+    ):
         owners = {s.device.id for s in leaf.addressable_shards}
         assert len(owners) == 4, f"an edge array sits on devices {owners}"
         assert all(

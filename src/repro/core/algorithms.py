@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..distributed.sharding import shard_frontier
+from .correction_rows import upload_correction
 from .engine import DeviceGraph, propagate, propagate_wedge
 from .semiring import MAX_MIN, MIN_PLUS, OR_AND, PLUS_TIMES, Semiring
 
@@ -657,12 +658,7 @@ def triangle_counts(
     block = max(1, min(int(block), n))
     wedge_dev = None
     if wedge is not None:
-        ws, wd, wm = tuple(wedge)
-        wedge_dev = (
-            jnp.asarray(ws, jnp.int32),
-            jnp.asarray(wd, jnp.int32),
-            jnp.asarray(wm, jnp.float32),
-        )
+        wedge_dev = upload_correction(*tuple(wedge), n)
         mode = "wedge"
     t = np.zeros(n, dtype=np.float64)
     for lo in range(0, n, block):

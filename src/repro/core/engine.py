@@ -16,7 +16,9 @@ on any representation:
   carried as a bit-packed block-sparse incidence so batched ring
   propagation feeds the MXU-aligned Pallas SpMM (DESIGN.md §6).
 * correction structure — DEDUP-C: C-DUP propagation minus a sparse
-  correction term makes ring propagation exact without rewriting edges.
+  correction term makes ring propagation exact without rewriting edges;
+  the term is applied from a destination-major row layout
+  (:mod:`repro.core.correction_rows`), with no scatter.
 
 **Batched frontiers** (DESIGN.md §3): ``x`` may be a single ``(n,)``
 vector or an ``(n, B)`` matrix of ``B`` independent frontiers (multi-source
@@ -41,6 +43,7 @@ import numpy as np
 from .. import obs
 from ..distributed.sharding import shard_frontier
 from .condensed import BipartiteEdges, CondensedGraph, ExpandedGraph
+from .correction_rows import DeviceCorrection, apply_correction, upload_correction
 from .semiring import PLUS_TIMES, Semiring, kernelizable, segment_reduce
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
@@ -81,11 +84,18 @@ KERNEL_DISPATCH_COUNT = 0
 # dispatch count.
 KERNEL_STANDDOWN_COUNT: dict = {}
 
+# Trace-time evidence of which path applied the DEDUP-C correction in
+# each traced ring propagation: ``'rows'`` (the row layout,
+# :func:`~repro.core.correction_rows.apply_correction`) or ``'fused'``
+# (the Pallas epilogue).  Reset together with the dispatch count.
+CORRECTION_EPILOGUE_COUNT: dict = {}
+
 
 def reset_kernel_dispatch_count() -> None:
     global KERNEL_DISPATCH_COUNT
     KERNEL_DISPATCH_COUNT = 0
     KERNEL_STANDDOWN_COUNT.clear()
+    CORRECTION_EPILOGUE_COUNT.clear()
 
 # A DEDUP-C correction as the engine accepts it: the plain (src, dst,
 # count) triples from build_correction, or the StreamedCorrection wrapper
@@ -152,8 +162,9 @@ class DeviceCondensed:
 
     ``chains``      list of chains; each chain a tuple of DeviceBipartite.
     ``direct``      optional real->real edges (may repeat = multiplicity).
-    ``correction``  optional (src, dst, count) triple; when present, ring
-                    propagation subtracts it (DEDUP-C).
+    ``correction``  optional DEDUP-C correction in its row layout
+                    (:class:`~repro.core.correction_rows.DeviceCorrection`);
+                    when present, ring propagation subtracts it.
     ``diag_mult``   per-node count of self paths (subtracted by ring
                     propagation so self-loops never contribute).
     ``deduplicated``True when path multiplicity is structurally 1
@@ -165,7 +176,7 @@ class DeviceCondensed:
 
     chains: Tuple[Tuple[DeviceBipartite, ...], ...]
     direct: Optional[DeviceBipartite]
-    correction: Optional[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]]
+    correction: Optional[DeviceCorrection]
     diag_mult: Optional[jnp.ndarray]
     n_real: int
     deduplicated: bool
@@ -287,11 +298,12 @@ class DevicePacked:
     ``fused_fwd`` / ``fused_rev`` carry the fused last-layer +
     DEDUP-C-epilogue operands (one per direction) when the graph has a
     correction; ring propagation then runs the subtraction inside the
-    kernel instead of as a separate segment_sum pass.  When they could
-    *not* be built, ``fused_standdown`` records the machine-readable
-    pack-time reason (``''`` when built; e.g. ``'unpackable_last_layer'``
-    — see :func:`_build_fused`), so dispatch-honesty tests pin why a
-    graph stood down instead of guessing.  Further trace-time stand-downs
+    kernel instead of applying the row layout after the last layer.  When
+    they could *not* be built, ``fused_standdown`` records the
+    machine-readable pack-time reason (``''`` when built; e.g.
+    ``'unpackable_last_layer'`` — see :func:`_build_fused`), so
+    dispatch-honesty tests pin why a graph stood down instead of
+    guessing.  Further trace-time stand-downs
     (1-D frontier, non-ring semiring, ``hop_weight``) are counted per
     reason in :data:`KERNEL_STANDDOWN_COUNT`.
 
@@ -302,7 +314,7 @@ class DevicePacked:
 
     chains: Tuple[Tuple[DevicePackedLayer, ...], ...]
     direct: Optional[DevicePackedLayer]
-    correction: Optional[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]]
+    correction: Optional[DeviceCorrection]
     diag_mult: Optional[jnp.ndarray]
     n_real: int
     deduplicated: bool
@@ -337,9 +349,9 @@ def with_graph_version(graph: DeviceGraph, version: int) -> DeviceGraph:
 
 def device_graph_bytes(graph: DeviceGraph) -> int:
     """Device bytes held by one uploaded graph: the sum over every pytree
-    leaf (edge arrays, packed bitmaps, fused operand streams, correction
-    triples).  This is the unit the serving tier's :class:`ResidencyBudget`
-    charges per resident tenant."""
+    leaf (edge arrays, packed bitmaps, fused operand streams, the
+    correction's row layout).  This is the unit the serving tier's
+    :class:`ResidencyBudget` charges per resident tenant."""
     total = 0
     for leaf in jax.tree_util.tree_leaves(graph):
         nbytes = getattr(leaf, "nbytes", None)
@@ -501,12 +513,7 @@ def to_device(
         corr = None
         triples = _correction_triples(correction)
         if triples is not None:
-            cs, cd, cm = triples
-            corr = (
-                jnp.asarray(cs, dtype=jnp.int32),
-                jnp.asarray(cd, dtype=jnp.int32),
-                jnp.asarray(cm, dtype=jnp.float32),
-            )
+            corr = upload_correction(*triples, graph.n_real)
         chains, direct, corr = _settled((chains, direct, corr))
     diag = None
     if drop_self_loops and corr is None:
@@ -1101,7 +1108,7 @@ def propagate(
 
     # Fused DEDUP-C epilogue (DESIGN.md §6): the last chain's final layer
     # and the correction subtraction run as one kernel launch; the
-    # trailing segment_sum correction below is then skipped.
+    # trailing row-layout correction below is then skipped.
     fused = None
     if isinstance(graph, DevicePacked) and graph.correction is not None:
         cand = graph.fused_rev if reverse else graph.fused_fwd
@@ -1145,18 +1152,16 @@ def propagate(
 
     if semiring.name == "plus_times":
         # Exactness corrections only make sense in the ring.
-        if graph.correction is not None and fused is not None:
-            pass  # already subtracted inside the fused kernel epilogue
-        elif graph.correction is not None:
-            with jax.named_scope("engine.correction"):
-                cs, cd, cm = graph.correction
-                src, dst = (cd, cs) if reverse else (cs, cd)
-                corr = jax.ops.segment_sum(
-                    _gather(x, src) * _bcast(cm, _gather(x, src)),
-                    dst,
-                    num_segments=graph.n_real,
-                )
-                y = y - _apply_hop(semiring, corr, hop_weight)
+        if graph.correction is not None:
+            path = "fused" if fused is not None else "rows"
+            CORRECTION_EPILOGUE_COUNT[path] = (
+                CORRECTION_EPILOGUE_COUNT.get(path, 0) + 1
+            )
+            # the fused kernel's epilogue has already subtracted it
+            if fused is None:
+                with jax.named_scope("engine.correction"):
+                    corr = apply_correction(graph.correction, x, reverse)
+                    y = y - _apply_hop(semiring, corr, hop_weight)
         elif graph.diag_mult is not None:
             y = y - _apply_hop(
                 semiring, x * _bcast(graph.diag_mult, x), hop_weight
@@ -1164,26 +1169,12 @@ def propagate(
     return shard_frontier(y)
 
 
-def _correction_apply(
-    triples: Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray],
-    x: jnp.ndarray,
-    n_real: int,
-    reverse: bool,
-) -> jnp.ndarray:
-    """``D·x`` (or ``Dᵀ·x``) for a sparse (src, dst, count) triple set."""
-    cs, cd, cm = triples
-    src, dst = (cd, cs) if reverse else (cs, cd)
-    return jax.ops.segment_sum(
-        _gather(x, src) * _bcast(cm, _gather(x, src)), dst, num_segments=n_real
-    )
-
-
 def propagate_wedge(
     graph: DeviceGraph,
     x: jnp.ndarray,
     *,
     reverse: bool = False,
-    wedge: Optional[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]] = None,
+    wedge: Optional[DeviceCorrection] = None,
 ) -> jnp.ndarray:
     """Exact two-hop ring propagation ``y = Aᵀ(Aᵀx)`` on a DEDUP-C graph
     from *uncorrected* C-DUP hops (DESIGN.md §11).
@@ -1196,10 +1187,11 @@ def propagate_wedge(
     kernel-path SpMM — no per-step correction subtraction, no fused
     epilogue needed) minus the *wedge correction* ``W = MD + DM − D²`` —
     the duplicate wedges whose legs are multiple condensed paths through
-    shared virtual nodes.  With ``wedge`` triples precomputed by
-    :func:`repro.core.dedup.build_wedge_correction` the correction is one
-    sparse pass (``y = M(Mx) − Wx``); without them it is assembled on the
-    fly from the graph's own ``D`` triples
+    shared virtual nodes.  With ``wedge`` precomputed by
+    :func:`repro.core.dedup.build_wedge_correction` and uploaded in its
+    row layout (:func:`~repro.core.correction_rows.upload_correction`)
+    the correction is one sparse pass (``y = M(Mx) − Wx``); without it it
+    is assembled on the fly from the graph's own ``D``
     (``y = M(Mx) − M(Dx) − D(Mx) + D(Dx)``).  Byte-identical to two
     per-step-corrected :func:`propagate` calls on integer frontiers.
     """
@@ -1218,13 +1210,11 @@ def propagate_wedge(
     mx = propagate(raw, x, PLUS_TIMES, reverse=reverse, allow_duplicates=True)
     mmx = propagate(raw, mx, PLUS_TIMES, reverse=reverse, allow_duplicates=True)
     if wedge is not None:
-        return shard_frontier(
-            mmx - _correction_apply(wedge, x, graph.n_real, reverse)
-        )
-    dx = _correction_apply(graph.correction, x, graph.n_real, reverse)
+        return shard_frontier(mmx - apply_correction(wedge, x, reverse))
+    dx = apply_correction(graph.correction, x, reverse)
     mdx = propagate(raw, dx, PLUS_TIMES, reverse=reverse, allow_duplicates=True)
-    dmx = _correction_apply(graph.correction, mx, graph.n_real, reverse)
-    ddx = _correction_apply(graph.correction, dx, graph.n_real, reverse)
+    dmx = apply_correction(graph.correction, mx, reverse)
+    ddx = apply_correction(graph.correction, dx, reverse)
     return shard_frontier(mmx - mdx - dmx + ddx)
 
 

@@ -20,6 +20,7 @@ import threading
 from typing import Mapping, Optional, Sequence, Tuple
 
 import jax
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = [
@@ -181,17 +182,20 @@ def edge_mesh(
 
 
 def shard_graph_edges(graph, mesh: Mesh):
-    """A :class:`~repro.core.engine.DeviceCondensed` with its edge and
-    correction arrays split across every device of ``mesh``.
+    """A :class:`~repro.core.engine.DeviceCondensed` with its edge arrays
+    and the rows of each correction width class split across every
+    device of ``mesh``.
 
     ``device_put`` needs divisible dims, so ragged edge lists are padded
     with *inert* entries: padded in-edges point real node 0 at a fresh
     dummy virtual node with no out-edges (and vice versa for out-edges),
     so no complete path, hence no propagated mass, is added.  Padded
-    correction triples carry count 0.
+    correction rows carry count 0; each node map, renumbered past them,
+    is replicated.
     """
     import jax.numpy as jnp
 
+    from ..core.correction_rows import CorrectionRows, DeviceCorrection
     from ..core.engine import DeviceBipartite, DeviceCondensed
 
     n_dev = mesh.devices.size
@@ -200,7 +204,8 @@ def shard_graph_edges(graph, mesh: Mesh):
     def padded(a, fill):
         pad = (-a.shape[0]) % n_dev
         if pad:
-            a = jnp.concatenate([a, jnp.full(pad, fill, a.dtype)])
+            fill = jnp.full((pad,) + a.shape[1:], fill, a.dtype)
+            a = jnp.concatenate([a, fill])
         return jax.device_put(a, spread)
 
     chains = []
@@ -219,10 +224,29 @@ def shard_graph_edges(graph, mesh: Mesh):
                 n_dst,
             ))
         chains.append(tuple(layers))
+    replicated = NamedSharding(mesh, PartitionSpec())
+
+    def shard_rows(rows):
+        sizes = np.array([0] + [i.shape[0] for i in rows.idx])
+        grown = sizes + (-sizes) % n_dev
+        # a node's row moves by the pad rows of the classes before its own
+        old, new = np.cumsum(sizes), np.cumsum(grown)
+        node_row = np.asarray(rows.node_row)
+        cls = np.searchsorted(old[1:], node_row, side="right")
+        node_row = node_row - old[cls] + new[cls]
+        return CorrectionRows(
+            tuple(padded(a, 0) for a in rows.idx),
+            tuple(padded(a, 0) for a in rows.weight),
+            jax.device_put(jnp.asarray(node_row, jnp.int32), replicated),
+        )
+
     corr = None
     if graph.correction is not None:
-        corr = tuple(padded(a, 0) for a in graph.correction)
-    replicated = NamedSharding(mesh, PartitionSpec())
+        rev = graph.correction.rev
+        corr = DeviceCorrection(
+            shard_rows(graph.correction.fwd),
+            None if rev is None else shard_rows(rev),
+        )
     diag = graph.diag_mult
     return DeviceCondensed(
         chains=tuple(chains),

@@ -349,39 +349,47 @@ def _graphgen_banded_cell(arch, cfg, shape_name, mesh) -> Cell:
                 (args_sh,), rules, cfg)
 
 
+# The dry-run's correction as one width class of this many slots a row
+# (the row layout of repro.core.correction_rows; real graphs have several).
+GRAPHGEN_CORRECTION_WIDTH = 8
+
+
 def _graphgen_cell(arch, arch_mod, cfg, shape_name, mesh) -> Cell:
     from ..core import algorithms, engine
+    from ..core.correction_rows import CorrectionRows, DeviceCorrection
 
     rules = dict(cfg.sharding_rules)
 
     def pagerank_step(args):
-        in_src, in_dst, cs, cd, cm, diag = (
-            args["in_src"], args["in_dst"], args["corr_src"],
-            args["corr_dst"], args["corr_cnt"], args["diag"],
-        )
+        in_src, in_dst = args["in_src"], args["in_dst"]
         fwd = engine.DeviceBipartite(in_src, in_dst, cfg.n_real, cfg.n_virtual)
         rev = engine.DeviceBipartite(in_dst, in_src, cfg.n_virtual, cfg.n_real)
+        rows = CorrectionRows(
+            (args["corr_idx"],), (args["corr_cnt"],), args["corr_row"]
+        )
         g = engine.DeviceCondensed(
             chains=((fwd, rev),),
             direct=None,
-            correction=(cs, cd, cm),
+            correction=DeviceCorrection(rows),
             diag_mult=None,
             n_real=cfg.n_real,
             deduplicated=False,
         )
         return algorithms.pagerank(g, num_iters=cfg.pagerank_iters)
 
-    E, C = cfg.n_in_edges, cfg.n_correction
+    E, K = cfg.n_in_edges, GRAPHGEN_CORRECTION_WIDTH
+    R = cfg.n_correction // K
     args_s = {
         "in_src": S((E,), jnp.int32),
         "in_dst": S((E,), jnp.int32),
-        "corr_src": S((C,), jnp.int32),
-        "corr_dst": S((C,), jnp.int32),
-        "corr_cnt": S((C,), jnp.float32),
+        "corr_idx": S((R, K), jnp.int32),
+        "corr_cnt": S((R, K), jnp.float32),
+        "corr_row": S((cfg.n_real,), jnp.int32),
         "diag": S((cfg.n_real,), jnp.float32),
     }
     e_sh = _ns(mesh, rules, ("edges",))
     args_sh = {k: e_sh for k in args_s}
+    args_sh["corr_row"] = _ns(mesh, rules, ("nodes",))
     args_sh["diag"] = _ns(mesh, rules, ("nodes",))
     return Cell(arch, shape_name, "analytics", pagerank_step, (args_s,),
                 (args_sh,), rules, cfg)

@@ -170,17 +170,19 @@ class _Executable:
     evidence (``traces[0]`` increments only when jax actually re-traces
     the wrapper — the honest no-retrace signal tests pin).  Its first
     trace also records the dispatch path it took: ``kernel_layers`` Pallas
-    layer steps in the traced program (a loop body counts once) and the
-    fused-epilogue ``standdowns`` by reason, which every call counts while
-    the recorder (:mod:`repro.obs`) is on.  A jitted helper that another
-    executable traced first (``out_degrees`` does not depend on the batch
-    width) is not traced again, so its decisions count under that
-    executable alone."""
+    layer steps in the traced program (a loop body counts once), the
+    fused-epilogue ``standdowns`` by reason, and the ``epilogues`` that
+    applied the DEDUP-C correction (``rows`` or ``fused``), which every
+    call counts while the recorder (:mod:`repro.obs`) is on.  A jitted
+    helper that another executable traced first (``out_degrees`` does
+    not depend on the batch width) is not traced again, so its decisions
+    count under that executable alone."""
 
     fn: object
     traces: List[int]
     kernel_layers: int = 0
     standdowns: Dict[str, int] = dataclasses.field(default_factory=dict)
+    epilogues: Tuple[str, ...] = ()
 
 
 class _Tenant:
@@ -578,6 +580,7 @@ class GraphServingTier:
             if first:
                 layers0 = _engine.KERNEL_DISPATCH_COUNT
                 standdowns0 = dict(_engine.KERNEL_STANDDOWN_COUNT)
+                epilogues0 = dict(_engine.CORRECTION_EPILOGUE_COUNT)
             out = raw(*args)
             if first:
                 entry.kernel_layers = _engine.KERNEL_DISPATCH_COUNT - layers0
@@ -586,6 +589,10 @@ class GraphServingTier:
                     for r, n in _engine.KERNEL_STANDDOWN_COUNT.items()
                     if n != standdowns0.get(r, 0)
                 }
+                entry.epilogues = tuple(sorted(
+                    p for p, n in _engine.CORRECTION_EPILOGUE_COUNT.items()
+                    if n != epilogues0.get(p, 0)
+                ))
             return out
 
         # the executable's name is the module name the device trace shows
@@ -722,6 +729,8 @@ class GraphServingTier:
         obs.count("tier.kernel_layer_calls", entry.kernel_layers)
         for reason, n in entry.standdowns.items():
             obs.count(f"tier.standdown.{reason}", n)
+        for path in entry.epilogues:
+            obs.count(f"tier.correction.{path}")
         with obs.span("tier.fetch"):
             res = np.asarray(out)
         dt = time.perf_counter() - t0

@@ -522,6 +522,44 @@ def test_kernel_layer_calls_count_every_call(monkeypatch, recording):
     assert entry.traces[0] == 1
 
 
+def _packed_ppr_tier(n_real):
+    # a size of its own per test: a program traced before (jitted helpers
+    # are cached by shape) records no trace-time decisions again
+    rng = np.random.default_rng(11)
+    tier = GraphServingTier(max_batch=4, result_cache=False)
+    tier.add_tenant("A", random_membership_graph(n_real, 12, 5, rng),
+                    packed=True, with_counts=False)
+    return tier
+
+
+def test_serve_ppr_correction_has_no_sort_or_scatter():
+    """The compiled PPR program applies the DEDUP-C correction from its row
+    layout: no instruction under ``engine.correction`` sorts or scatters."""
+    import re
+
+    tier = _packed_ppr_tier(41)
+    tier.serve(_reqs("A", "ppr", range(4)))
+    (key, entry), = tier._executables.items()
+    assert entry.epilogues == ("rows",)
+    graph = engine.with_graph_version(tier.tenants["A"].device, 0)
+    text = entry.fn.lower(graph, np.zeros(key[1], np.int32)).compile().as_text()
+    ops = re.findall(r"= \S+ (\w[\w-]*)\(.*op_name=\"([^\"]*)\"", text)
+    correction = [op for op, name in ops if "engine.correction" in name]
+    assert correction, "no instruction carries the engine.correction scope"
+    assert not [op for op in correction if op in ("sort", "scatter")], correction
+
+
+def test_correction_rows_count_once_per_ppr_call(recording):
+    tier = _packed_ppr_tier(43)
+    for calls in (1, 2, 3):
+        tier.serve(_reqs("A", "ppr", range(4), qid0=10 * calls))
+        counts = recording.snapshot()["counts"]
+        assert counts["tier.correction.rows"] == calls
+        assert "tier.correction.fused" not in counts
+    (entry,) = tier._executables.values()
+    assert entry.traces[0] == 1
+
+
 def test_queue_wait_samples_match_requests_served(recording):
     tier = _two_tenant_tier()
     first = tier.serve(_reqs("A", "bfs", range(6)))
