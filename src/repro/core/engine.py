@@ -32,6 +32,7 @@ algorithms take them as arguments.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import TYPE_CHECKING, List, Optional, Sequence, Tuple, Union
@@ -90,10 +91,17 @@ KERNEL_STANDDOWN_COUNT: dict = {}
 # (the Pallas epilogue).  Reset together with the dispatch count.
 CORRECTION_EPILOGUE_COUNT: dict = {}
 
+# Trace-time count of interior layer steps: those whose input and output
+# are both virtual layers (a chain of two or more virtual layers has
+# them; each runs under the ``engine.interior`` device scope).  Reset
+# together with the dispatch count.
+INTERIOR_STEP_COUNT = 0
+
 
 def reset_kernel_dispatch_count() -> None:
-    global KERNEL_DISPATCH_COUNT
+    global KERNEL_DISPATCH_COUNT, INTERIOR_STEP_COUNT
     KERNEL_DISPATCH_COUNT = 0
+    INTERIOR_STEP_COUNT = 0
     KERNEL_STANDDOWN_COUNT.clear()
     CORRECTION_EPILOGUE_COUNT.clear()
 
@@ -1019,6 +1027,18 @@ def _fused_layer_spmm(
     return yp[: fused.n_out, :f]
 
 
+def _interior_scope(step: int, n_steps: int):
+    """``engine.interior`` around step ``step`` of a chain of ``n_steps``
+    when it runs from one virtual layer to another (counted in
+    :data:`INTERIOR_STEP_COUNT`); nothing around a step that reads or
+    writes the real nodes."""
+    global INTERIOR_STEP_COUNT
+    if not 0 < step < n_steps - 1:
+        return contextlib.nullcontext()
+    INTERIOR_STEP_COUNT += 1
+    return jax.named_scope("engine.interior")
+
+
 def _apply_hop(sr: Semiring, y: jnp.ndarray, hop_weight: Optional[float]) -> jnp.ndarray:
     if hop_weight is None:
         return y
@@ -1132,7 +1152,7 @@ def propagate(
         h = x
         fuse_here = fused is not None and ci == len(graph.chains) - 1
         for si, e in enumerate(seq[:-1] if fuse_here else seq):
-            with jax.named_scope("engine.layer"):
+            with jax.named_scope("engine.layer"), _interior_scope(si, len(seq)):
                 h = _layer_propagate(graph, semiring, e, h, reverse)
                 if w_seq is not None and si < len(seq) - 1:
                     h = semiring.mul(h, _bcast(jnp.asarray(w_seq[si]), h))

@@ -171,18 +171,20 @@ class _Executable:
     the wrapper — the honest no-retrace signal tests pin).  Its first
     trace also records the dispatch path it took: ``kernel_layers`` Pallas
     layer steps in the traced program (a loop body counts once), the
-    fused-epilogue ``standdowns`` by reason, and the ``epilogues`` that
-    applied the DEDUP-C correction (``rows`` or ``fused``), which every
-    call counts while the recorder (:mod:`repro.obs`) is on.  A jitted
-    helper that another executable traced first (``out_degrees`` does
-    not depend on the batch width) is not traced again, so its decisions
-    count under that executable alone."""
+    fused-epilogue ``standdowns`` by reason, the ``epilogues`` that
+    applied the DEDUP-C correction (``rows`` or ``fused``), and its
+    ``interior_steps`` (layer steps from one virtual layer to another),
+    which every call counts while the recorder (:mod:`repro.obs`) is on.
+    A jitted helper that another executable traced first (``out_degrees``
+    does not depend on the batch width) is not traced again, so its
+    decisions count under that executable alone."""
 
     fn: object
     traces: List[int]
     kernel_layers: int = 0
     standdowns: Dict[str, int] = dataclasses.field(default_factory=dict)
     epilogues: Tuple[str, ...] = ()
+    interior_steps: int = 0
 
 
 class _Tenant:
@@ -581,6 +583,7 @@ class GraphServingTier:
                 layers0 = _engine.KERNEL_DISPATCH_COUNT
                 standdowns0 = dict(_engine.KERNEL_STANDDOWN_COUNT)
                 epilogues0 = dict(_engine.CORRECTION_EPILOGUE_COUNT)
+                interior0 = _engine.INTERIOR_STEP_COUNT
             out = raw(*args)
             if first:
                 entry.kernel_layers = _engine.KERNEL_DISPATCH_COUNT - layers0
@@ -593,6 +596,7 @@ class GraphServingTier:
                     p for p, n in _engine.CORRECTION_EPILOGUE_COUNT.items()
                     if n != epilogues0.get(p, 0)
                 ))
+                entry.interior_steps = _engine.INTERIOR_STEP_COUNT - interior0
             return out
 
         # the executable's name is the module name the device trace shows
@@ -731,6 +735,7 @@ class GraphServingTier:
             obs.count(f"tier.standdown.{reason}", n)
         for path in entry.epilogues:
             obs.count(f"tier.correction.{path}")
+        obs.count("tier.interior_steps", entry.interior_steps)
         with obs.span("tier.fetch"):
             res = np.asarray(out)
         dt = time.perf_counter() - t0

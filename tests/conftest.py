@@ -110,6 +110,35 @@ def random_bipartite(n_src, n_dst, n_edges, rng, unique=True):
     return BipartiteEdges(key % n_src, key // n_src, n_src, n_dst)
 
 
+Q_COPURCHASE = """
+Nodes(ID, Name) :- Customer(ID, Name).
+Edges(ID1, ID2) :- Orders(ok1, ID1), LineItem(ok1, pk),
+                   Orders(ok2, ID2), LineItem(ok2, pk).
+"""
+
+
+def copurchase_graph(n_customers, n_orders, n_parts, rng):
+    """A TPC-H-shaped co-purchase graph: each order belongs to a customer
+    whose key is not a multiple of 3, holds 1-7 distinct parts, and two
+    customers meet through a part of an order of each.  A chain of three
+    virtual layers (orders, parts, orders), condensed by ``extract``."""
+    from repro.core.extract import extract
+    from repro.core.relational import Catalog, Table
+
+    ordering = np.flatnonzero(np.arange(n_customers) % 3 != 0)
+    lines = rng.integers(1, 8, size=n_orders)
+    parts = [rng.choice(n_parts, size=k, replace=False) for k in lines]
+    catalog = Catalog([
+        Table("Customer", {"ckey": np.arange(n_customers),
+                           "name": np.arange(n_customers).astype(str)}),
+        Table("Orders", {"okey": np.arange(n_orders) + 1000,
+                         "ckey": rng.choice(ordering, size=n_orders)}),
+        Table("LineItem", {"okey": np.repeat(np.arange(n_orders), lines) + 1000,
+                           "pkey": np.concatenate(parts) + 5000}),
+    ])
+    return extract(catalog, Q_COPURCHASE, mode="condensed").graph
+
+
 def random_multilayer_graph(n_real, layer_sizes, density, rng):
     levels = [n_real] + list(layer_sizes) + [n_real]
     edges = []

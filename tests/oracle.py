@@ -28,6 +28,7 @@ __all__ = [
     "reachable_ref",
     "connected_components_ref",
     "common_neighbors_ref",
+    "personalized_pagerank_ref",
     "scc_labels_ref",
     "condensation_ref",
     "triangle_counts_ref",
@@ -167,6 +168,22 @@ def common_neighbors_ref(M: np.ndarray, nodes) -> np.ndarray:
     the dense multiplicity matrix per queried node."""
     nodes = np.atleast_1d(np.asarray(nodes))
     return M[nodes].T.astype(np.float64)
+
+
+def personalized_pagerank_ref(A: np.ndarray, nodes, damping: float = 0.85,
+                              iters: int = 20) -> np.ndarray:
+    """``(n, len(nodes))`` personalized PageRank by dense power iteration,
+    one one-hot restart column per node; a node with no out-edge returns
+    its mass to the restart column."""
+    deg = A.sum(axis=1)[:, None]
+    seeds = np.zeros((A.shape[0], len(nodes)))
+    seeds[np.asarray(nodes), np.arange(len(nodes))] = 1.0
+    x = seeds
+    for _ in range(iters):
+        contrib = np.where(deg > 0, x / np.maximum(deg, 1.0), 0.0)
+        dangling = np.where(deg > 0, 0.0, x).sum(axis=0)
+        x = (1 - damping) * seeds + damping * (A.T @ contrib + dangling * seeds)
+    return x
 
 
 # ---------------------------------------------------------------------------

@@ -100,10 +100,20 @@ def test_four_chip_phase_on_four_virtual_devices():
     assert "FOUR_OK" in proc.stdout
 
 
+CACHE_SETTINGS = (
+    "jax_compilation_cache_dir",
+    "jax_compilation_cache_include_metadata_in_key",
+    "jax_hlo_source_file_canonicalization_regex",
+    "jax_persistent_cache_min_compile_time_secs",
+    "jax_persistent_cache_min_entry_size_bytes",
+)
+
+
 def test_compile_cache_dir_is_fixed(monkeypatch):
     from repro.launch import compile_cache
 
     was = jax.config.jax_compilation_cache_dir
+    settings = {k: getattr(jax.config, k) for k in CACHE_SETTINGS}
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
         assert compile_cache.enable_compile_cache() == "/elsewhere"
@@ -113,4 +123,46 @@ def test_compile_cache_dir_is_fixed(monkeypatch):
         assert path == os.path.join(os.path.realpath(REPO), ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == path
     finally:
-        jax.config.update("jax_compilation_cache_dir", was)
+        for k, v in settings.items():
+            jax.config.update(k, v)
+
+
+def test_cached_programs_keep_their_own_scopes(tmp_path):
+    """Two programs that differ in a named scope alone are two cache
+    entries: the second does not load the first's executable, whose ops
+    would name the first's scope in the device trace."""
+    import re
+
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch import compile_cache
+
+    was = {k: getattr(jax.config, k) for k in CACHE_SETTINGS}
+    try:
+        compile_cache.enable_compile_cache()
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        compilation_cache.reset_cache()
+
+        def program(scope):
+            def f(x):
+                with jax.named_scope(scope):
+                    return jnp.sin(x) * 3.0 + 1.0
+            return f
+
+        x = jnp.ones(16)
+        texts = [jax.jit(program(s)).lower(x).compile().as_text()
+                 for s in ("engine.layer", "engine.interior")]
+        assert "engine.layer/" in texts[0] and "engine.interior/" in texts[1]
+        assert "engine.layer/" not in texts[1]
+        assert len(list(tmp_path.glob("jit_f-*-cache"))) == 2
+        # source files enter the key relative to the checkout
+        regex = jax.config.jax_hlo_source_file_canonicalization_regex
+        engine = compile_cache.CHECKOUT / "src" / "repro" / "core" / "engine.py"
+        assert re.sub(regex, "", str(engine)) == os.path.join("src", "repro", "core", "engine.py")
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()

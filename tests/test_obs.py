@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import random_membership_graph
+from conftest import copurchase_graph, random_membership_graph
 
 from repro import obs
 from repro.core import dedup, engine
@@ -146,11 +146,9 @@ def test_packed_upload_splits_packing_from_transfer():
     assert spans["engine.pack"]["seconds"] + spans["engine.upload"]["seconds"] <= wall
 
 
-@pytest.fixture(scope="module")
-def ppr_hlo():
-    """HLO text of one packed PPR batch, as lowered (with its debug info)
-    and as compiled for the CPU."""
-    g = _graph(3)
+def _ppr_batch_hlo(g):
+    """HLO text of one packed PPR batch over ``g``, as lowered (with its
+    debug info) and as compiled for the CPU."""
     tier = GraphServingTier(max_batch=8)
     tier.add_tenant("A", g, packed=True)
     tier._ensure_resident(tier.tenants["A"])
@@ -161,15 +159,42 @@ def ppr_hlo():
             "compiled": lowered.compile().as_text()}
 
 
-@pytest.mark.parametrize("stage", ["lowered", "compiled"])
-@pytest.mark.parametrize("scope", ["engine.layer", "engine.correction", "ppr.update"])
-def test_packed_ppr_batch_carries_the_device_scopes(ppr_hlo, stage, scope):
-    """Each scope is a path element of some instruction's location, and
+def _carries(text, stage, scope):
+    """``scope`` is a path element of some instruction's location, and
     after compiling of its ``op_name``, which the device trace carries as
     the op's ``tf_op``."""
     key = {"lowered": "loc", "compiled": "op_name="}[stage]
-    assert re.search(re.escape(key) + r'\(?"(?:[^"]*/)?' + re.escape(scope) + "/",
-                     ppr_hlo[stage])
+    return re.search(re.escape(key) + r'\(?"(?:[^"]*/)?' + re.escape(scope) + "/", text)
+
+
+@pytest.fixture(scope="module")
+def ppr_hlo():
+    return _ppr_batch_hlo(_graph(3))
+
+
+@pytest.fixture(scope="module")
+def copurchase_hlo():
+    """A chain of three virtual layers: orders, parts, orders."""
+    return _ppr_batch_hlo(copurchase_graph(42, 130, 26, np.random.default_rng(3)))
+
+
+@pytest.mark.parametrize("stage", ["lowered", "compiled"])
+@pytest.mark.parametrize("scope", ["engine.layer", "engine.correction", "ppr.update"])
+def test_packed_ppr_batch_carries_the_device_scopes(ppr_hlo, stage, scope):
+    assert _carries(ppr_hlo[stage], stage, scope)
+
+
+@pytest.mark.parametrize("stage", ["lowered", "compiled"])
+@pytest.mark.parametrize("chain", ["three_virtual_layers", "one_virtual_layer"])
+def test_interior_scope_marks_only_steps_between_virtual_layers(
+        ppr_hlo, copurchase_hlo, stage, chain):
+    """``engine.interior`` runs inside ``engine.layer`` on a step from one
+    virtual layer to another; a chain of one virtual layer has none."""
+    text = {"three_virtual_layers": copurchase_hlo, "one_virtual_layer": ppr_hlo}[chain]
+    assert bool(_carries(text[stage], stage, "engine.layer/engine.interior")) == (
+        chain == "three_virtual_layers")
+    assert bool(_carries(text[stage], stage, "engine.interior")) == (
+        chain == "three_virtual_layers")
 
 
 def test_fused_epilogue_carries_its_scope():

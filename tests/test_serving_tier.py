@@ -20,7 +20,8 @@ The tier's four contracts, each tested where it can actually break:
 import numpy as np
 import pytest
 
-from conftest import random_membership_graph
+from conftest import copurchase_graph, random_membership_graph
+from oracle import dense_adjacency, personalized_pagerank_ref
 
 from repro.core import dedup, engine
 from repro.core.delta import LiveGraph
@@ -354,6 +355,22 @@ def test_tier_answers_match_direct_algorithms():
         assert np.array_equal(got[i], want[:, i]), q
 
 
+def test_copurchase_ppr_batch_matches_the_dense_oracle():
+    """A TPC-H-shaped chain of three virtual layers (orders, parts,
+    orders), served packed: each column of one PPR batch is the dense
+    oracle's personalized PageRank, customers that never order included."""
+    g = copurchase_graph(45, 150, 30, np.random.default_rng(21))
+    assert [len(c.layer_sizes) for c in g.chains] == [3]
+    tier = GraphServingTier(max_batch=8, result_cache=False)
+    tier.add_tenant("T", g, packed=True, with_counts=False)
+    nodes = [0, 1, 2, 3, 5, 8, 40, 44]
+    got = tier.serve(_reqs("T", "ppr", nodes))
+    want = personalized_pagerank_ref(dense_adjacency(g), nodes,
+                                     tier.damping, tier.ppr_iters)
+    for i in range(len(nodes)):
+        np.testing.assert_allclose(got[i], want[:, i], atol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # Condensation-native analytics kinds (DESIGN.md §11)
 # ---------------------------------------------------------------------------
@@ -558,6 +575,30 @@ def test_correction_rows_count_once_per_ppr_call(recording):
         assert "tier.correction.fused" not in counts
     (entry,) = tier._executables.values()
     assert entry.traces[0] == 1
+
+
+def test_interior_steps_count_every_call(recording):
+    """A chain of three virtual layers has two interior steps a hop; the
+    traced program records them once and every call counts them."""
+    tier = GraphServingTier(max_batch=4, result_cache=False)
+    tier.add_tenant("T", copurchase_graph(47, 160, 31, np.random.default_rng(2)),
+                    packed=True, with_counts=False)
+    for calls in (1, 2, 3):
+        tier.serve(_reqs("T", "ppr", range(4), qid0=10 * calls))
+        (entry,) = tier._executables.values()
+        assert recording.snapshot()["counts"]["tier.interior_steps"] == (
+            calls * entry.interior_steps)
+    # the loop body's two steps and the out-degrees' two: a graph of its
+    # own size, so the jitted out-degrees is traced inside this program
+    assert entry.interior_steps == 4
+    assert entry.traces[0] == 1
+    counted = recording.snapshot()["counts"]["tier.interior_steps"]
+    # one virtual layer: no interior step, nothing more counted
+    single = _packed_ppr_tier(45)
+    single.serve(_reqs("A", "ppr", range(4)))
+    (entry,) = single._executables.values()
+    assert entry.interior_steps == 0
+    assert recording.snapshot()["counts"]["tier.interior_steps"] == counted
 
 
 def test_queue_wait_samples_match_requests_served(recording):
