@@ -12,8 +12,8 @@ Every answer is checked against a plain host computation on the catalog
 rows (NumPy, and SciPy's sparse product for the expanded co-author
 graph).  A kernel phase then runs ``backend='pallas'`` propagation (the
 ``sum``, ``min`` and fused DEDUP-C kernels) on a graph small enough for
-the kernels' slot tables, and compares it with the segment path on the
-same chip.
+the kernels' slot tables, and compares it with the XLA path
+(``backend='xla'``: the layers' row layouts) on the same chip.
 
 ``--four-chips`` runs only the edge-sharded PageRank of the same graph on
 a 2x2 mesh and compares it with one-device PageRank in this process.
@@ -288,13 +288,13 @@ def check_answers(kind, nodes, values, ref: HostReference, tier) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Kernel phase: backend='pallas' against the segment path, same chip
+# Kernel phase: backend='pallas' against backend='xla', same chip
 # ---------------------------------------------------------------------------
 
 def kernel_phase(b: Build, seed: int = SEED) -> dict:
     """The ``sum``, fused and ``min`` kernels on one graph, each compared
-    with the segment path; every compared value is an exact integer in
-    f32, so the two paths must agree bit for bit."""
+    with the XLA path (``segment_seconds`` times it); every compared value
+    is an exact integer in f32, so the two paths must agree bit for bit."""
     import jax
     import jax.numpy as jnp
     from repro.core import algorithms, engine

@@ -12,9 +12,12 @@ on any representation:
 * ``DeviceCondensed``  — C-DUP / DEDUP-1: one segment-reduce per condensed
   layer (the 2-hop factorized SpMV, ``y = B_out^T (B_in^T x)``); path
   multiplicity is counted by ring semirings and ignored by idempotent ones.
-* ``DevicePacked``     — the same condensed semantics with each layer also
-  carried as a bit-packed block-sparse incidence so batched ring
-  propagation feeds the MXU-aligned Pallas SpMM (DESIGN.md §6).
+* ``DevicePacked``     — the same condensed semantics with each layer
+  carried in a destination-major row layout in both directions
+  (:mod:`repro.core.correction_rows`: a gather and a ⊕-reduce per width
+  class, no sort, no scatter) and as a bit-packed block-sparse incidence
+  so batched ring propagation can feed the MXU-aligned Pallas SpMM
+  (DESIGN.md §6).
 * correction structure — DEDUP-C: C-DUP propagation minus a sparse
   correction term makes ring propagation exact without rewriting edges;
   the term is applied from a destination-major row layout
@@ -44,7 +47,14 @@ import numpy as np
 from .. import obs
 from ..distributed.sharding import shard_frontier
 from .condensed import BipartiteEdges, CondensedGraph, ExpandedGraph
-from .correction_rows import DeviceCorrection, apply_correction, upload_correction
+from .correction_rows import (
+    DeviceCorrection,
+    RowLayout,
+    apply_correction,
+    apply_layout,
+    row_layout,
+    upload_correction,
+)
 from .semiring import PLUS_TIMES, Semiring, kernelizable, segment_reduce
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
@@ -91,6 +101,12 @@ KERNEL_STANDDOWN_COUNT: dict = {}
 # (the Pallas epilogue).  Reset together with the dispatch count.
 CORRECTION_EPILOGUE_COUNT: dict = {}
 
+# Trace-time evidence of which path ran each layer step of a
+# DevicePacked graph: ``'rows'`` (the row layout,
+# :func:`~repro.core.correction_rows.apply_layout`) or ``'pallas'`` (the
+# bit-packed SpMM kernel).  Reset together with the dispatch count.
+LAYER_STEP_COUNT: dict = {}
+
 # Trace-time count of interior layer steps: those whose input and output
 # are both virtual layers (a chain of two or more virtual layers has
 # them; each runs under the ``engine.interior`` device scope).  Reset
@@ -104,6 +120,7 @@ def reset_kernel_dispatch_count() -> None:
     INTERIOR_STEP_COUNT = 0
     KERNEL_STANDDOWN_COUNT.clear()
     CORRECTION_EPILOGUE_COUNT.clear()
+    LAYER_STEP_COUNT.clear()
 
 # A DEDUP-C correction as the engine accepts it: the plain (src, dst,
 # count) triples from build_correction, or the StreamedCorrection wrapper
@@ -253,24 +270,26 @@ class FusedOperands:
 
 @partial(
     jax.tree_util.register_dataclass,
-    data_fields=["src", "dst", "fwd", "rev"],
+    data_fields=["rows_fwd", "rows_rev", "fwd", "rev"],
     meta_fields=["n_src", "n_dst", "n_src_pad", "n_dst_pad"],
 )
 @dataclasses.dataclass
 class DevicePackedLayer:
-    """One condensed layer in COO plus bit-packed streamed-slot form.
+    """One condensed layer in row layouts plus bit-packed streamed-slot form.
 
-    ``src``/``dst`` drive the segment-reduce path (any semiring, any
-    direction).  ``fwd`` is the dst-major packed incidence
-    (:mod:`repro.kernels.pack`) consumed by the Pallas SpMM for batched
-    forward propagation; ``rev`` packs the transposed incidence so
-    ``reverse=True`` steps (HITS, out-degrees) dispatch to the kernel
-    too.  Either is ``None`` when the layer is not packable (duplicate
-    edges, e.g. multiplicity-carrying direct edges).
+    ``rows_fwd`` (rows by destination) and ``rows_rev`` (rows by source)
+    drive the XLA path of a forward and a reverse step under any semiring
+    (:func:`~repro.core.correction_rows.apply_layout`); the incidence is
+    unweighted, so they depend on the graph alone.  ``fwd`` is the
+    dst-major packed incidence (:mod:`repro.kernels.pack`) consumed by the
+    Pallas SpMM for batched forward propagation; ``rev`` packs the
+    transposed incidence so ``reverse=True`` steps (HITS, out-degrees)
+    dispatch to the kernel too.  Either is ``None`` when the layer is not
+    packable (duplicate edges, e.g. multiplicity-carrying direct edges).
     """
 
-    src: jnp.ndarray
-    dst: jnp.ndarray
+    rows_fwd: RowLayout
+    rows_rev: RowLayout
     fwd: Optional[PackedOperands]
     rev: Optional[PackedOperands]
     n_src: int
@@ -518,28 +537,41 @@ def to_device(
     with obs.span("engine.upload"):
         chains = tuple(tuple(_dev_edges(e) for e in c.edges) for c in graph.chains)
         direct = _dev_edges(graph.direct) if graph.direct is not None else None
-        corr = None
-        triples = _correction_triples(correction)
-        if triples is not None:
-            corr = upload_correction(*triples, graph.n_real)
+        corr = _upload_correction(graph, correction)
         chains, direct, corr = _settled((chains, direct, corr))
-    diag = None
-    if drop_self_loops and corr is None:
-        # Full self-path multiplicity: DEDUP-1's uniqueness invariant is
-        # off-diagonal only — u reaches itself once per containing virtual
-        # node, and all of those must be subtracted.
-        counts = self_path_counts(graph)
-        with obs.span("engine.upload"):
-            diag = _settled(jnp.asarray(counts, dtype=jnp.float32))
     return DeviceCondensed(
         chains=chains,
         direct=direct,
         correction=corr,
-        diag_mult=diag,
+        diag_mult=_self_path_diag(graph, corr, drop_self_loops),
         n_real=graph.n_real,
         deduplicated=deduplicated,
         graph_version=int(graph_version),
     )
+
+
+def _upload_correction(
+    graph: CondensedGraph, correction: Optional[Correction]
+) -> Optional[DeviceCorrection]:
+    triples = _correction_triples(correction)
+    if triples is None:
+        return None
+    return upload_correction(*triples, graph.n_real)
+
+
+def _self_path_diag(
+    graph: CondensedGraph,
+    corr: Optional[DeviceCorrection],
+    drop_self_loops: bool,
+) -> Optional[jnp.ndarray]:
+    if not drop_self_loops or corr is not None:
+        return None
+    # Full self-path multiplicity: DEDUP-1's uniqueness invariant is
+    # off-diagonal only — u reaches itself once per containing virtual
+    # node, and all of those must be subtracted.
+    counts = self_path_counts(graph)
+    with obs.span("engine.upload"):
+        return _settled(jnp.asarray(counts, dtype=jnp.float32))
 
 
 def _settled(tree):
@@ -562,39 +594,38 @@ def _upload_operands(bsb, crossover=None) -> PackedOperands:
         ))
 
 
-def _measure_direction(bsb, dev_src, dev_dst, n_src, n_dst, measure_kwargs):
+def _measure_direction(bsb, rows: RowLayout, n_src, n_dst, measure_kwargs):
     """Record a crossover table for one packed direction by racing the
-    kernel (autotuned) against the segment path on this host."""
+    kernel (autotuned) against the row layout, the XLA path that runs
+    when the kernel does not."""
     from ..kernels.autotune import measure_crossover
     from ..kernels.ops import PackedLayer
 
     layer = PackedLayer(
-        bsb=bsb,
-        bsb_rev=None,
-        src=dev_src,
-        dst=dev_dst,
-        n_src=n_src,
-        n_dst=n_dst,
+        bsb=bsb, bsb_rev=None, src=None, dst=None, n_src=n_src, n_dst=n_dst
     )
-    return measure_crossover(layer, **measure_kwargs)
+    rows = jax.tree_util.tree_map(jnp.asarray, rows)
+    return measure_crossover(
+        layer, xla=lambda x, sr: apply_layout(rows, x, sr), **measure_kwargs
+    )
 
 
 def _pack_edges(
     e: BipartiteEdges,
-    dev: DeviceBipartite,
     shard_edges: Optional[int] = None,
     measure: bool = False,
     measure_kwargs: Optional[dict] = None,
     pack_method: str = "reduceat",
 ):
-    """``dev`` is the already-uploaded COO layer from :func:`to_device`,
-    reused so the edge arrays cross to the device only once.  Packs both
-    directions: the forward incidence and its transpose (reverse steps).
-    ``shard_edges`` routes the packing through the shard-at-a-time path
-    (:func:`repro.kernels.pack.pack_bipartite` slices + OR-merge,
-    DESIGN.md §7) so packing transients stay bounded for large layers.
-    ``measure`` additionally races each direction against the segment
-    path and stores the crossover table on the uploaded operands.
+    """Build one layer's row layouts in both directions on the host, and
+    pack and upload both directions' kernel operands: the forward
+    incidence and its transpose (reverse steps).  The row layouts stay
+    NumPy here; :func:`to_device_packed` uploads them in one transfer with
+    the correction.  ``shard_edges`` routes the packing through the
+    shard-at-a-time path (:func:`repro.kernels.pack.pack_bipartite` slices
+    + OR-merge, DESIGN.md §7) so packing transients stay bounded for large
+    layers.  ``measure`` additionally races each direction against the
+    row layout and stores the crossover table on the uploaded operands.
 
     Returns ``(DevicePackedLayer, fwd_bsb, rev_bsb)`` — the host-side
     packings ride along so :func:`to_device_packed` can build the fused
@@ -607,29 +638,27 @@ def _pack_edges(
     # (BlockSparseBitmap.n_src_tiles): zero-node layers stay kernel-safe
     n_src_pad = max(-(-e.n_src // TILE), 1) * TILE
     n_dst_pad = max(-(-e.n_dst // TILE), 1) * TILE
-    try:
-        with obs.span("engine.pack"):
+    with obs.span("engine.pack"):
+        rows_fwd = row_layout(e.src, e.dst, e.n_src, e.n_dst)
+        rows_rev = row_layout(e.dst, e.src, e.n_dst, e.n_src)
+        try:
             fwd_bsb = pack_bipartite(e, method=pack_method, shard_edges=shard_edges)
             rev_bsb = pack_bipartite(
                 e.reversed(), method=pack_method, shard_edges=shard_edges
             )
+        except ValueError:
+            fwd_bsb = rev_bsb = None  # duplicate edges (multiplicity): rows only
+    if fwd_bsb is not None:
         fwd_table = rev_table = None
         if measure:
             kw = measure_kwargs or {}
-            fwd_table = _measure_direction(
-                fwd_bsb, dev.src, dev.dst, e.n_src, e.n_dst, kw
-            )
-            rev_table = _measure_direction(
-                rev_bsb, dev.dst, dev.src, e.n_dst, e.n_src, kw
-            )
+            fwd_table = _measure_direction(fwd_bsb, rows_fwd, e.n_src, e.n_dst, kw)
+            rev_table = _measure_direction(rev_bsb, rows_rev, e.n_dst, e.n_src, kw)
         fwd = _upload_operands(fwd_bsb, fwd_table)
         rev = _upload_operands(rev_bsb, rev_table)
-    except ValueError:
-        fwd = rev = None  # duplicate edges (multiplicity): COO path only
-        fwd_bsb = rev_bsb = None
     layer = DevicePackedLayer(
-        src=dev.src,
-        dst=dev.dst,
+        rows_fwd=rows_fwd,
+        rows_rev=rows_rev,
         fwd=fwd,
         rev=rev,
         n_src=e.n_src,
@@ -723,10 +752,11 @@ def to_device_packed(
     graph_version: int = 0,
     pack_method: str = "reduceat",
 ) -> DevicePacked:
-    """Like :func:`to_device`, additionally packing every condensed layer
-    into bit-packed block-sparse SpMM operands (DESIGN.md §6) so batched
-    ring propagation runs on the Pallas kernel.  Correction / dedup
-    semantics are identical to :func:`to_device` (streamed corrections
+    """Like :func:`to_device`, but every condensed layer is held as its row
+    layouts in both directions (DESIGN.md §2) instead of COO edges, and is
+    also packed into bit-packed block-sparse SpMM operands (DESIGN.md §6)
+    so batched ring propagation can run on the Pallas kernel.  Correction
+    / dedup semantics are identical to :func:`to_device` (streamed corrections
     accepted the same way).  ``pack_shard_edges`` bounds the host packing
     transients per layer (shard-at-a-time packing, DESIGN.md §7) — the
     uploaded operands are byte-identical either way.
@@ -734,7 +764,7 @@ def to_device_packed(
     ``fuse_correction`` (default on) also builds the fused last-layer +
     DEDUP-C-epilogue operands when a correction is present, so batched
     ring propagation subtracts the correction inside the kernel.
-    ``measure=True`` races each packed direction against the segment path
+    ``measure=True`` races each packed direction against its row layout
     at pack time and records the crossover table on the operands
     (:mod:`repro.kernels.autotune`); 'auto' dispatch then follows the
     measurement.  ``measure_kwargs`` forwards to
@@ -744,31 +774,28 @@ def to_device_packed(
     knob — DESIGN.md §12); the packed operands are byte-identical either
     way.
     """
-    base = to_device(
-        graph,
-        correction=correction,
-        deduplicated=deduplicated,
-        drop_self_loops=drop_self_loops,
-    )
-    assert isinstance(base, DeviceCondensed)
     chains_host = tuple(
         tuple(
-            _pack_edges(
-                e, d, pack_shard_edges, measure, measure_kwargs, pack_method
-            )
-            for e, d in zip(c.edges, dc)
+            _pack_edges(e, pack_shard_edges, measure, measure_kwargs, pack_method)
+            for e in c.edges
         )
-        for c, dc in zip(graph.chains, base.chains)
+        for c in graph.chains
     )
-    chains = tuple(tuple(t[0] for t in c) for c in chains_host)
     direct = (
         _pack_edges(
-            graph.direct, base.direct, pack_shard_edges, measure,
-            measure_kwargs, pack_method,
+            graph.direct, pack_shard_edges, measure, measure_kwargs, pack_method
         )[0]
         if graph.direct is not None
         else None
     )
+    with obs.span("engine.upload"):
+        # the row layouts (NumPy leaves; packed operands are on the
+        # device already) cross in one transfer with the correction
+        chains, direct = jax.tree_util.tree_map(
+            jnp.asarray, (tuple(tuple(t[0] for t in c) for c in chains_host), direct)
+        )
+        corr = _upload_correction(graph, correction)
+        chains, direct, corr = _settled((chains, direct, corr))
     fused_fwd = fused_rev = None
     triples = _correction_triples(correction)
     if triples is None:
@@ -785,8 +812,8 @@ def to_device_packed(
     return DevicePacked(
         chains=chains,
         direct=direct,
-        correction=base.correction,
-        diag_mult=base.diag_mult,
+        correction=corr,
+        diag_mult=_self_path_diag(graph, corr, drop_self_loops),
         n_real=graph.n_real,
         deduplicated=deduplicated,
         backend=backend,
@@ -935,11 +962,13 @@ def _layer_propagate(
     x: jnp.ndarray,
     reverse: bool,
 ) -> jnp.ndarray:
-    if isinstance(graph, DevicePacked) and _kernel_applicable(
-        graph, edges, x, sr, reverse
-    ):
+    if not isinstance(graph, DevicePacked):
+        return _edge_propagate(sr, edges, x, reverse)
+    path = "pallas" if _kernel_applicable(graph, edges, x, sr, reverse) else "rows"
+    LAYER_STEP_COUNT[path] = LAYER_STEP_COUNT.get(path, 0) + 1
+    if path == "pallas":
         return _packed_layer_spmm(edges, x, graph.feature_block, sr, reverse)
-    return _edge_propagate(sr, edges, x, reverse)
+    return apply_layout(edges.rows_rev if reverse else edges.rows_fwd, x, sr)
 
 
 def _fused_applicable(

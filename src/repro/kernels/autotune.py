@@ -301,11 +301,14 @@ def measure_crossover(
     candidates: Sequence[KernelConfig] = CANDIDATES,
     interpret: Optional[bool] = None,
     time_fn: Optional[TimeFn] = None,
+    xla: Optional[Callable[[object, object], object]] = None,
 ) -> CrossoverTable:
-    """Race Pallas (autotuned per cell) against the XLA segment path and
-    record the winners.  Called at pack time when measurement is requested
+    """Race Pallas (autotuned per cell) against the XLA path and record
+    the winners.  Called at pack time when measurement is requested
     (``PackedLayer.from_edges(..., measure=True)`` /
-    ``engine.to_device_packed(..., measure_crossover=True)``)."""
+    ``engine.to_device_packed(..., measure=True)``).  ``xla(x, semiring)``
+    is the XLA step that runs in the kernel's place; left None, the
+    layer's segment path."""
     import jax.numpy as jnp
 
     from . import ops as _ops
@@ -326,6 +329,9 @@ def measure_crossover(
             x = jnp.ones((layer.n_src, b), jnp.float32)
 
             def run_xla():
+                if xla is not None:
+                    xla(x, semiring).block_until_ready()
+                    return
                 _ops.bitmap_spmm(
                     layer, x, backend="xla", semiring=semiring
                 ).block_until_ready()

@@ -172,9 +172,11 @@ class _Executable:
     trace also records the dispatch path it took: ``kernel_layers`` Pallas
     layer steps in the traced program (a loop body counts once), the
     fused-epilogue ``standdowns`` by reason, the ``epilogues`` that
-    applied the DEDUP-C correction (``rows`` or ``fused``), and its
-    ``interior_steps`` (layer steps from one virtual layer to another),
-    which every call counts while the recorder (:mod:`repro.obs`) is on.
+    applied the DEDUP-C correction (``rows`` or ``fused``), its
+    ``layer_paths`` (layer steps of a packed graph by the path each took,
+    ``rows`` or ``pallas``) and its ``interior_steps`` (layer steps from
+    one virtual layer to another), which every call counts while the
+    recorder (:mod:`repro.obs`) is on.
     A jitted helper that another executable traced first (``out_degrees``
     does not depend on the batch width) is not traced again, so its
     decisions count under that executable alone."""
@@ -184,6 +186,7 @@ class _Executable:
     kernel_layers: int = 0
     standdowns: Dict[str, int] = dataclasses.field(default_factory=dict)
     epilogues: Tuple[str, ...] = ()
+    layer_paths: Dict[str, int] = dataclasses.field(default_factory=dict)
     interior_steps: int = 0
 
 
@@ -583,6 +586,7 @@ class GraphServingTier:
                 layers0 = _engine.KERNEL_DISPATCH_COUNT
                 standdowns0 = dict(_engine.KERNEL_STANDDOWN_COUNT)
                 epilogues0 = dict(_engine.CORRECTION_EPILOGUE_COUNT)
+                paths0 = dict(_engine.LAYER_STEP_COUNT)
                 interior0 = _engine.INTERIOR_STEP_COUNT
             out = raw(*args)
             if first:
@@ -596,6 +600,11 @@ class GraphServingTier:
                     p for p, n in _engine.CORRECTION_EPILOGUE_COUNT.items()
                     if n != epilogues0.get(p, 0)
                 ))
+                entry.layer_paths = {
+                    p: n - paths0.get(p, 0)
+                    for p, n in _engine.LAYER_STEP_COUNT.items()
+                    if n != paths0.get(p, 0)
+                }
                 entry.interior_steps = _engine.INTERIOR_STEP_COUNT - interior0
             return out
 
@@ -735,6 +744,8 @@ class GraphServingTier:
             obs.count(f"tier.standdown.{reason}", n)
         for path in entry.epilogues:
             obs.count(f"tier.correction.{path}")
+        for path, n in entry.layer_paths.items():
+            obs.count(f"tier.layer.{path}", n)
         obs.count("tier.interior_steps", entry.interior_steps)
         with obs.span("tier.fetch"):
             res = np.asarray(out)

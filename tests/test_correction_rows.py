@@ -1,6 +1,7 @@
 """The DEDUP-C correction's row layout (``repro.core.correction_rows``):
 its apply against a NumPy ``D·x`` / ``Dᵀ·x`` from the raw triples, the
-build's invariants, and the layout sharded over four virtual devices."""
+build's invariants, the shared builder against the correction's own loop,
+and the layout sharded over four virtual devices."""
 import os
 import subprocess
 import sys
@@ -137,6 +138,52 @@ def test_class_widths_bound_each_row():
     assert set(cr.class_width(np.array([1, 2, 3, 4, 5, 7, 9, 13]))) == {
         1, 2, 3, 4, 6, 8, 12, 16,
     }
+
+
+def _loop_correction_rows(src, dst, weight, n):
+    """The correction's layout as it was built before the layers shared
+    its builder: the reference the shared builder must still meet."""
+    src = np.asarray(src, dtype=np.int64)
+    dst = np.asarray(dst, dtype=np.int64)
+    weight = np.asarray(weight, dtype=np.float32)
+    order = np.argsort(dst * max(int(n), 1) + src, kind="stable")
+    cols = src[order].astype(np.int32)
+    w = weight[order]
+    widths = np.bincount(dst, minlength=n).astype(np.int64)
+    starts = np.cumsum(widths) - widths
+    nodes = np.flatnonzero(widths)
+    k_of = cr.class_width(widths[nodes])
+    idx, wts = [], []
+    node_row = np.empty(n, dtype=np.int32)
+    offset = 0
+    for k in np.unique(k_of):
+        members = nodes[k_of == k]
+        slot = np.arange(k)
+        live = slot[None, :] < widths[members][:, None]
+        take = np.where(live, starts[members][:, None] + slot[None, :], 0)
+        idx.append(np.where(live, cols[take], 0).astype(np.int32))
+        wts.append(np.where(live, w[take], 0).astype(np.float32))
+        node_row[members] = offset + np.arange(members.size, dtype=np.int32)
+        offset += members.size
+    empty = np.ones(n, dtype=bool)
+    empty[nodes] = False
+    node_row[empty] = offset
+    return idx, wts, node_row
+
+
+@pytest.mark.parametrize("reverse", [False, True], ids=["fwd", "rev"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_shared_builder_keeps_the_correction_layout(triples, case, reverse):
+    src, dst, w, n = triples(case)
+    if reverse:
+        src, dst = dst, src
+    rows = cr.correction_rows(src, dst, w, n)
+    idx, wts, node_row = _loop_correction_rows(src, dst, w, n)
+    assert np.array_equal(rows.node_row, node_row)
+    assert node_row.dtype == rows.node_row.dtype == np.int32
+    assert len(rows.idx) == len(idx) == len(rows.weight)
+    for a, b in zip(rows.idx + rows.weight, tuple(idx) + tuple(wts)):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
 
 
 def test_layout_ignores_triple_order(triples):

@@ -549,21 +549,59 @@ def _packed_ppr_tier(n_real):
     return tier
 
 
+def _compiled_ops(tier, kind):
+    """``(opcode, op_name)`` of every instruction of the tier's compiled
+    ``serve_<kind>`` program, fused computations included."""
+    import re
+
+    (key, entry), = tier._executables.items()
+    graph = engine.with_graph_version(tier.tenants["A"].graph_for(kind), 0)
+    text = entry.fn.lower(graph, np.zeros(key[1], np.int32)).compile().as_text()
+    return re.findall(r"= \S+ (\w[\w-]*)\(.*op_name=\"([^\"]*)\"", text)
+
+
 def test_serve_ppr_correction_has_no_sort_or_scatter():
     """The compiled PPR program applies the DEDUP-C correction from its row
     layout: no instruction under ``engine.correction`` sorts or scatters."""
-    import re
-
     tier = _packed_ppr_tier(41)
     tier.serve(_reqs("A", "ppr", range(4)))
-    (key, entry), = tier._executables.items()
+    (entry,) = tier._executables.values()
     assert entry.epilogues == ("rows",)
-    graph = engine.with_graph_version(tier.tenants["A"].device, 0)
-    text = entry.fn.lower(graph, np.zeros(key[1], np.int32)).compile().as_text()
-    ops = re.findall(r"= \S+ (\w[\w-]*)\(.*op_name=\"([^\"]*)\"", text)
+    ops = _compiled_ops(tier, "ppr")
     correction = [op for op, name in ops if "engine.correction" in name]
     assert correction, "no instruction carries the engine.correction scope"
     assert not [op for op in correction if op in ("sort", "scatter")], correction
+
+
+@pytest.mark.parametrize("kind,n_real", [("ppr", 49), ("bfs", 51)])
+def test_served_layer_steps_have_no_sort_or_scatter(kind, n_real):
+    """The condensed layer steps of a served program run from the layers'
+    row layouts: no instruction under ``engine.layer`` sorts or scatters,
+    under the ring sum (PPR) and under ``min`` (BFS) alike."""
+    tier = _packed_ppr_tier(n_real)
+    tier.serve(_reqs("A", kind, range(4)))
+    (entry,) = tier._executables.values()
+    assert entry.layer_paths.get("rows", 0) > 0
+    assert "pallas" not in entry.layer_paths
+    layer = [op for op, name in _compiled_ops(tier, kind) if "engine.layer" in name]
+    assert layer, "no instruction carries the engine.layer scope"
+    assert not [op for op in layer if op in ("sort", "scatter")], layer
+
+
+def test_layer_rows_count_every_call(recording):
+    """Every call counts its program's layer steps under the path each
+    took; the traced program records them once."""
+    tier = _packed_ppr_tier(53)
+    for calls in (1, 2, 3):
+        tier.serve(_reqs("A", "ppr", range(4), qid0=10 * calls))
+        (entry,) = tier._executables.values()
+        counts = recording.snapshot()["counts"]
+        assert counts["tier.layer.rows"] == calls * entry.layer_paths["rows"]
+        assert "tier.layer.pallas" not in counts
+    # the loop body's two steps and the out-degrees' two: a graph of its
+    # own size, so the jitted out-degrees is traced inside this program
+    assert entry.layer_paths == {"rows": 4}
+    assert entry.traces[0] == 1
 
 
 def test_correction_rows_count_once_per_ppr_call(recording):
